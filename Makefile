@@ -55,7 +55,7 @@ sweep-smoke:
 runtime-smoke:
 	timeout 1500 $(PY) -m pytest tests/test_runtime.py -q
 	PYTHONPATH=src timeout 300 $(PY) -m repro.launch.serve \
-	    --arch igpm-pem --async --scenario flash_crowd \
+	    --arch igpm-pem --smoke --async --scenario flash_crowd \
 	    --rate 3000 --ticks 12 --bank 4
 
 # shared sub-pattern decomposition: the refcounted-DAG suite (bitwise
@@ -87,7 +87,7 @@ control-smoke:
 	PYTHONPATH=src:. timeout 600 $(PY) benchmarks/serving_bench.py \
 	    --smoke --control-only
 	PYTHONPATH=src timeout 300 $(PY) -m repro.launch.serve \
-	    --arch igpm-pem --async --scenario flash_crowd --rate 2000 \
+	    --arch igpm-pem --smoke --async --scenario flash_crowd --rate 2000 \
 	    --ticks 10 --closed-loop --control frozen --control-episodes 1
 
 # edge-partitioned storage + multi-executor scale-out (DESIGN.md §10):

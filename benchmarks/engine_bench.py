@@ -187,6 +187,7 @@ def _graph_worker(n_devices: int, n_max: int, smoke: bool,
 
 def _run_forced(n_devices: int, extra_args: List[str]) -> dict:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices never open a chip
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
                         + env.get("XLA_FLAGS", "")).strip()
     env["PYTHONPATH"] = "src:." + (
@@ -265,6 +266,11 @@ def main() -> None:
     ap.add_argument("--devices", type=int, default=1, help=argparse.SUPPRESS)
     ap.add_argument("--nmax", type=int, default=1024, help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if args.worker or args.graph_worker:
+        # only the forced-device children touch JAX: the sweep parent
+        # stays off every backend so it never holds a chip
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
     if args.worker:
         _worker(args.devices, args.smoke)
         return

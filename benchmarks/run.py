@@ -1,6 +1,9 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``
 # CSV. ``--quick`` shrinks twins/steps for CI; results used in
-# EXPERIMENTS.md come from the default scale.
+# EXPERIMENTS.md come from the default scale. Every suite runs in this one
+# process. The multi-device engine sweep starts one child per forced
+# device count, so it runs in its own invocation instead
+# (``benchmarks/engine_bench.py``) and never under a parent holding a chip.
 from __future__ import annotations
 
 import argparse
@@ -18,10 +21,13 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
 
-    from benchmarks import (engine_bench, fig5_batch_vs_inc, fig6_queries,
-                            fig7_adaptive, fig9_patterns, fig_backends,
-                            kernels_bench, roofline_table, scaling,
-                            serving_bench, table2_compat)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
+    from benchmarks import (fig5_batch_vs_inc, fig6_queries, fig7_adaptive,
+                            fig9_patterns, fig_backends, kernels_bench,
+                            roofline_table, scaling, serving_bench,
+                            table2_compat)
     suites = {
         "fig5": fig5_batch_vs_inc.run,
         "fig6": fig6_queries.run,
@@ -33,9 +39,12 @@ def main() -> None:
         "roofline": roofline_table.run,
         "scaling": scaling.run,
         "serving": serving_bench.run,
-        "engine": engine_bench.run,
     }
     picked = args.only or list(suites)
+    unknown = sorted(set(picked) - set(suites))
+    if unknown:
+        raise SystemExit(f"unknown suites {unknown} (have: {sorted(suites)};"
+                         " the engine sweep is benchmarks/engine_bench.py)")
     kw = {}
     if args.scale is not None:
         kw["scale"] = args.scale
@@ -54,8 +63,6 @@ def main() -> None:
             skw = dict(kw)
             if name in ("kernels", "roofline"):
                 skw = {}
-            elif name == "engine":  # forced-device subprocess sweep:
-                skw = {"smoke": True} if args.quick else {}
             for row in suites[name](**skw):
                 print(row.csv(), flush=True)
         except Exception as e:  # keep the harness going, fail at exit

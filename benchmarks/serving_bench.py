@@ -41,6 +41,7 @@ import numpy as np
 
 from benchmarks.common import BenchRow, write_json
 from repro.config.base import IGPMConfig, ServingConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.core.query import query_zoo
 from repro.data.temporal import TemporalGraphSpec, generate_stream
 from repro.serving import MatchServer
@@ -618,6 +619,7 @@ def main() -> None:
                     help="run ONLY the closed-loop controller rows "
                          "(make control-smoke; no summary artifact)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.control_only:
         rows = _control_rows(smoke=args.smoke)
         for r in rows:

@@ -36,6 +36,7 @@ from repro.config.base import (IGPMConfig, ObsConfig, RuntimeConfig,
                                ServingConfig)
 from repro.core.query import query_zoo
 from repro.data.temporal import TemporalGraphSpec, generate_stream
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import Obs, read_jsonl, validate_exposition, validate_jsonl
 from repro.serving import MatchServer
 
@@ -216,5 +217,6 @@ def _flash_crowd_artifact() -> BenchRow:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     for r in run():
         print(r.csv())

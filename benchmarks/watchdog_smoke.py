@@ -36,6 +36,7 @@ from benchmarks.common import OUT_DIR
 from repro.config.base import (IGPMConfig, ObsConfig, RuntimeConfig,
                                ServingConfig)
 from repro.core.query import query_zoo
+from repro.launch.compile_cache import use_compile_cache
 from repro.runtime import (ServingRuntime, VirtualClock, build_workload,
                            flash_crowd, run_workload_sync)
 from repro.serving import MatchServer
@@ -158,4 +159,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run()

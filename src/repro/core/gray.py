@@ -376,18 +376,82 @@ class BankGRayMatcher:
         hops = jnp.zeros((B, k, qe_max), jnp.int32)
         valid = seed_mask
 
-        # per-(query, source) tables, all first-uses of one unrolled step
-        # batched into ONE shared (n, P·k) RWR + reach sweep
+        def step_one(ei, lq, matched_q, used_q, goodness_q, hops_q, valid_q,
+                     qb, tr, on, r_q, reach_q):
+            # neighbor-expander: best label-compatible unused candidate
+            cand_ok = ((g.labels == lq[qb])[None, :]
+                       & g.node_mask[None, :] & ~used_q)           # (k, n)
+            score = jnp.where(cand_ok, r_q.T, -jnp.inf)
+            best = jnp.argmax(score, axis=1).astype(jnp.int32)
+            found = jnp.isfinite(jnp.max(score, axis=1))
+            m_tree = jnp.where(found, best, -1)
+            m_non = matched_q[:, qb]   # non-tree: both ends matched
+            write = tr & on
+            matched_q = matched_q.at[:, qb].set(
+                jnp.where(write, m_tree, m_non))
+            used_q = used_q.at[jnp.arange(k), best].set(
+                used_q[jnp.arange(k), best] | (found & write))
+            prox_tree = r_q[best, jnp.arange(k)]
+            prox_non = r_q[jnp.clip(m_non, 0, n - 1), jnp.arange(k)]
+            delta = jnp.where(tr,
+                              jnp.where(found,
+                                        jnp.log(prox_tree + _EPS), 0.0),
+                              jnp.log(prox_non + _EPS))
+            goodness_q = goodness_q + jnp.where(on, delta, 0.0)
+            valid_q = valid_q & jnp.where(write, found, True)
+            # bridge: hop count of best path (1 ⇒ exact edge)
+            m_b = jnp.where(tr, m_tree, m_non)
+            h = reach_q[jnp.arange(k), jnp.clip(m_b, 0, n - 1)]
+            hops_q = hops_q.at[:, ei].set(
+                jnp.where(on, h, hops_q[:, ei]))
+            return matched_q, used_q, goodness_q, hops_q, valid_q
+
+        def expand(ei, state, r_t, reach_t):
+            """One schedule step for every row: (matched, used, goodness,
+            hops, valid) → the same, reading the step's tables."""
+            return jax.vmap(lambda *a: step_one(ei, *a))(
+                q_labels, *state, order_dst[:, ei], order_tree[:, ei],
+                order_mask[:, ei], r_t, reach_t)
+
+        def sweep_tables(flat):
+            """One shared RWR + reach sweep block for ``flat`` sources."""
+            e = jax.nn.one_hot(flat, n, dtype=jnp.float32).T   # (n, S)
+            r_new = self._rwr(g, e, ell, graph_axis, part)
+            h_new = _bfs_reach_hops(g, flat, self.bridge_hops, ell=ell,
+                                    axis=graph_axis, part=part)
+            return r_new, h_new
+
+        state = (matched, used, goodness, hops, valid)
         if self.memo:
+            # per-(query, source) tables, all first-uses of one unrolled
+            # step batched into ONE shared (n, P·k) RWR + reach sweep
             tables_r = jnp.zeros((B, self.t_max, n, k), jnp.float32)
             tables_h = jnp.zeros((B, self.t_max, k, n), jnp.int32)
+            for ei in range(self.n_steps):
+                pairs = self._new_pairs[ei]
+                if pairs:
+                    srcs = jnp.stack([state[0][b, :, sv]
+                                      for b, _, sv in pairs])    # (P, k)
+                    p = len(pairs)
+                    r_new, h_new = sweep_tables(srcs.reshape(p * k))
+                    r_new = jnp.transpose(r_new.reshape(n, p, k), (1, 0, 2))
+                    b_idx = jnp.asarray([b for b, _, _ in pairs])
+                    t_idx = jnp.asarray([t for _, t, _ in pairs])
+                    tables_r = tables_r.at[b_idx, t_idx].set(r_new)
+                    tables_h = tables_h.at[b_idx, t_idx].set(
+                        h_new.reshape(p, k, n))
+                slot = jnp.asarray(self._read_slot[ei])
+                state = expand(ei, state,
+                               tables_r[jnp.arange(B), slot],    # (B, n, k)
+                               tables_h[jnp.arange(B), slot])    # (B, k, n)
         else:
-            # shared sub-pattern tables: ONE slot per DAG node plus a
-            # trash slot (n_slots) swallowing masked reads and packing
-            # fill, "node computed" tracked as traced data. row_node maps
-            # (row, step) → the node whose tables the step reads; the
-            # identity plan (node ≡ row·q_max + source vertex) reproduces
-            # the legacy per-(row, vertex) layout exactly.
+            # content-independent memo over sub-pattern DAG nodes: ONE
+            # table slot per node plus a trash slot (n_slots) swallowing
+            # masked reads and packing fill, "node computed" tracked as
+            # traced data. row_node maps (row, step) → the node whose
+            # tables the step reads; the identity plan (node ≡ row·q_max +
+            # source vertex) reproduces the legacy per-(row, vertex)
+            # layout exactly.
             if row_node is None:
                 n_slots = B * q_max
                 row_node = (jnp.arange(B, dtype=jnp.int32)[:, None] * q_max
@@ -397,45 +461,21 @@ class BankGRayMatcher:
                     "row_node plans need a node_cap-sized matcher"
                 n_slots = int(self.node_cap)
             n_sweep = min(B, n_slots)
-            tables_r = jnp.zeros((n_slots + 1, n, k), jnp.float32)
-            tables_h = jnp.zeros((n_slots + 1, k, n), jnp.int32)
-            node_seen = jnp.zeros(n_slots + 1, jnp.int32)
 
-        for ei in range(self.n_steps):
-            if self.memo:
-                pairs = self._new_pairs[ei]
-                if pairs:
-                    srcs = jnp.stack([matched[b, :, sv]
-                                      for b, _, sv in pairs])    # (P, k)
-                    p = len(pairs)
-                    flat = srcs.reshape(p * k)
-                    e = jax.nn.one_hot(flat, n,
-                                       dtype=jnp.float32).T      # (n, P·k)
-                    r_new = self._rwr(g, e, ell, graph_axis, part)
-                    r_new = jnp.transpose(r_new.reshape(n, p, k), (1, 0, 2))
-                    h_new = _bfs_reach_hops(g, flat, self.bridge_hops,
-                                            ell=ell, axis=graph_axis,
-                                            part=part).reshape(p, k, n)
-                    b_idx = jnp.asarray([b for b, _, _ in pairs])
-                    t_idx = jnp.asarray([t for _, t, _ in pairs])
-                    tables_r = tables_r.at[b_idx, t_idx].set(r_new)
-                    tables_h = tables_h.at[b_idx, t_idx].set(h_new)
-                slot = jnp.asarray(self._read_slot[ei])
-                r_t = tables_r[jnp.arange(B), slot]              # (B, n, k)
-                reach_t = tables_h[jnp.arange(B), slot]          # (B, k, n)
-            else:
-                # content-independent memo over DAG nodes: "node computed"
-                # is DATA, and the step's shared (n, n_sweep·k) sweep is
-                # guarded by a lax.cond on "any row reads a node not
-                # computed yet" — all derived from the order/row_node
-                # tensors, which are jit arguments. Sweep count matches
-                # the host-static memo (padded tail steps and repeated
-                # sources skip at runtime) while the compiled structure
-                # depends only on the bucket shape, so membership swaps
-                # never retrace. Every row holding a node expands through
-                # bitwise-identical partials (DESIGN.md §7), so one
-                # representative row per fresh node computes its tables
-                # for the whole bank.
+            def step(ei, carry):
+                # each step's shared (n, n_sweep·k) sweep is guarded by a
+                # lax.cond on "any row reads a node not computed yet" —
+                # all derived from the order/row_node tensors, which are
+                # jit arguments. Sweep count matches the host-static memo
+                # (padded tail steps and repeated sources skip at runtime)
+                # while the compiled structure depends only on the bucket
+                # shape, so membership swaps never retrace; the steps run
+                # as one rolled loop, so a program holds ONE sweep block
+                # whatever its schedule length. Every row holding a node
+                # expands through bitwise-identical partials (DESIGN.md
+                # §7), so one representative row per fresh node computes
+                # its tables for the whole bank.
+                state, tables_r, tables_h, node_seen = carry
                 on = order_mask[:, ei]                           # (B,)
                 nd = jnp.where(on, row_node[:, ei],
                                n_slots).astype(jnp.int32)        # (B,)
@@ -448,66 +488,34 @@ class BankGRayMatcher:
                 idx = jnp.nonzero(rep[:n_slots] < B, size=n_sweep,
                                   fill_value=n_slots)[0]         # (n_sweep,)
 
-                def compute(tabs, matched=matched, rep=rep, idx=idx):
+                def compute(tabs):
                     t_r, t_h = tabs
                     rows = jnp.clip(rep[idx], 0, B - 1)          # (n_sweep,)
                     srcv = order_src[rows, ei]                   # (n_sweep,)
                     srcs = jnp.take_along_axis(
-                        matched[rows], srcv[:, None, None],
+                        state[0][rows], srcv[:, None, None],
                         axis=2)[:, :, 0]                         # (n_sweep, k)
-                    flat = srcs.reshape(n_sweep * k)
-                    e = jax.nn.one_hot(flat, n,
-                                       dtype=jnp.float32).T  # (n, n_sweep·k)
-                    r_new = self._rwr(g, e, ell, graph_axis, part)
+                    r_new, h_new = sweep_tables(srcs.reshape(n_sweep * k))
                     r_new = jnp.transpose(r_new.reshape(n, n_sweep, k),
                                           (1, 0, 2))
-                    h_new = _bfs_reach_hops(
-                        g, flat, self.bridge_hops, ell=ell,
-                        axis=graph_axis, part=part).reshape(n_sweep, k, n)
                     # packing fill (idx == n_slots) lands in the trash
                     # slot, which only masked reads ever see
-                    return t_r.at[idx].set(r_new), t_h.at[idx].set(h_new)
+                    return (t_r.at[idx].set(r_new),
+                            t_h.at[idx].set(h_new.reshape(n_sweep, k, n)))
 
                 tables_r, tables_h = jax.lax.cond(
                     fresh.any(), compute, lambda t: t, (tables_r, tables_h))
                 node_seen = node_seen.at[nd].max(on.astype(jnp.int32))
-                r_t = tables_r[nd]                               # (B, n, k)
-                reach_t = tables_h[nd]                           # (B, k, n)
+                state = expand(ei, state, tables_r[nd],         # (B, n, k)
+                               tables_h[nd])                     # (B, k, n)
+                return state, tables_r, tables_h, node_seen
 
-            def step_one(lq, matched_q, used_q, goodness_q, hops_q, valid_q,
-                         qb, tr, on, r_q, reach_q, ei=ei):
-                # neighbor-expander: best label-compatible unused candidate
-                cand_ok = ((g.labels == lq[qb])[None, :]
-                           & g.node_mask[None, :] & ~used_q)       # (k, n)
-                score = jnp.where(cand_ok, r_q.T, -jnp.inf)
-                best = jnp.argmax(score, axis=1).astype(jnp.int32)
-                found = jnp.isfinite(jnp.max(score, axis=1))
-                m_tree = jnp.where(found, best, -1)
-                m_non = matched_q[:, qb]   # non-tree: both ends matched
-                write = tr & on
-                matched_q = matched_q.at[:, qb].set(
-                    jnp.where(write, m_tree, m_non))
-                used_q = used_q.at[jnp.arange(k), best].set(
-                    used_q[jnp.arange(k), best] | (found & write))
-                prox_tree = r_q[best, jnp.arange(k)]
-                prox_non = r_q[jnp.clip(m_non, 0, n - 1), jnp.arange(k)]
-                delta = jnp.where(tr,
-                                  jnp.where(found,
-                                            jnp.log(prox_tree + _EPS), 0.0),
-                                  jnp.log(prox_non + _EPS))
-                goodness_q = goodness_q + jnp.where(on, delta, 0.0)
-                valid_q = valid_q & jnp.where(write, found, True)
-                # bridge: hop count of best path (1 ⇒ exact edge)
-                m_b = jnp.where(tr, m_tree, m_non)
-                h = reach_q[jnp.arange(k), jnp.clip(m_b, 0, n - 1)]
-                hops_q = hops_q.at[:, ei].set(
-                    jnp.where(on, h, hops_q[:, ei]))
-                return matched_q, used_q, goodness_q, hops_q, valid_q
-
-            matched, used, goodness, hops, valid = jax.vmap(step_one)(
-                q_labels, matched, used, goodness, hops, valid,
-                order_dst[:, ei], order_tree[:, ei], order_mask[:, ei],
-                r_t, reach_t)
+            state, _, _, _ = jax.lax.fori_loop(
+                0, self.n_steps, step,
+                (state, jnp.zeros((n_slots + 1, n, k), jnp.float32),
+                 jnp.zeros((n_slots + 1, k, n), jnp.int32),
+                 jnp.zeros(n_slots + 1, jnp.int32)))
+        matched, used, goodness, hops, valid = state
 
         em = order_mask[:, None, :]                             # (B, 1, qe)
         exact = jnp.where(em, hops == 1, True).all(axis=2)

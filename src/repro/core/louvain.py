@@ -32,44 +32,58 @@ def _csr(senders: np.ndarray, receivers: np.ndarray, weights: np.ndarray,
 def _one_level(offs: np.ndarray, nbr: np.ndarray, w: np.ndarray, n: int,
                resolution: float, rng: np.random.Generator,
                max_sweeps: int = 10) -> np.ndarray:
-    """Phase 1: greedy local moves maximizing modularity gain."""
-    comm = np.arange(n)
+    """Phase 1: greedy local moves maximizing modularity gain.
+
+    The per-vertex move runs on Python scalars (lists, a dict per
+    neighborhood): neighborhoods are a handful of arcs, where numpy's
+    per-call overhead dominated. Float operations and their order are
+    those of the array formulation — per-community weights summed in arc
+    order, gains evaluated and the first maximum taken in ascending
+    community order — so the partition is unchanged."""
     k = np.zeros(n)  # weighted degree
     np.add.at(k, np.repeat(np.arange(n), np.diff(offs)), w)
-    two_m = max(w.sum(), 1e-12)  # directed sum == 2m for symmetric input
-    sigma_tot = k.copy()  # per-community total degree
+    two_m = float(max(w.sum(), 1e-12))  # directed sum == 2m for symmetric input
+    comm = list(range(n))
+    kl = k.tolist()
+    sigma_tot = k.tolist()  # per-community total degree
+    offs_l, nbr_l, w_l = offs.tolist(), nbr.tolist(), w.tolist()
 
     for _ in range(max_sweeps):
         moved = 0
-        for v in rng.permutation(n):
-            lo, hi = offs[v], offs[v + 1]
+        for v in rng.permutation(n).tolist():
+            lo, hi = offs_l[v], offs_l[v + 1]
             if lo == hi:
                 continue
-            ncomm = comm[nbr[lo:hi]]
             cv = comm[v]
             # weight from v to each neighboring community
-            uniq, inv = np.unique(ncomm, return_inverse=True)
-            w_to = np.bincount(inv, weights=w[lo:hi])
-            sigma_tot[cv] -= k[v]
+            w_to: dict = {}
+            for j in range(lo, hi):
+                c = comm[nbr_l[j]]
+                w_to[c] = w_to.get(c, 0.0) + w_l[j]
+            kv = kl[v]
+            sigma_tot[cv] -= kv
             # ΔQ ∝ w_to(c) − γ·k_v·Σ_tot(c)/2m  (v removed from cv first)
-            gain = w_to - resolution * k[v] * sigma_tot[uniq] / two_m
-            best = uniq[int(np.argmax(gain))]
+            rk = resolution * kv
+            best, best_gain, base = cv, None, None
+            for c in sorted(w_to):
+                gain = w_to[c] - rk * sigma_tot[c] / two_m
+                if best_gain is None or gain > best_gain:
+                    best, best_gain = c, gain
+                if c == cv:
+                    base = gain
             # gain of staying put: w_to(cv) may be 0 if no neighbor shares cv
-            where_cv = np.where(uniq == cv)[0]
-            if len(where_cv):
-                base = gain[int(where_cv[0])]
-            else:
-                base = -resolution * k[v] * sigma_tot[cv] / two_m
-            if gain.max() > base + 1e-12 and best != cv:
+            if base is None:
+                base = -resolution * kv * sigma_tot[cv] / two_m
+            if best_gain > base + 1e-12 and best != cv:
                 comm[v] = best
-                sigma_tot[best] += k[v]
+                sigma_tot[best] += kv
                 moved += 1
             else:
-                sigma_tot[cv] += k[v]
+                sigma_tot[cv] += kv
         if moved == 0:
             break
     # relabel densely
-    _, comm = np.unique(comm, return_inverse=True)
+    _, comm = np.unique(np.asarray(comm, np.int64), return_inverse=True)
     return comm
 
 
@@ -202,22 +216,30 @@ def build_dendrogram(senders: np.ndarray, receivers: np.ndarray, n: int,
                      min_size: int = 2, seed: int = 0,
                      max_depth: int = 32) -> Dendrogram:
     """Recursively split the graph with Louvain (resolution bump per level,
-    balanced chunking for indivisible communities) down to ``min_size``."""
+    balanced chunking for indivisible communities) down to ``min_size``.
+
+    Tree nodes are numbered in depth-first preorder. A split buckets the
+    parent's members and intra-child edges by child community with one
+    stable sort each, so it costs about the parent's size once — not once
+    per child, which at serving capacities (hundreds of thousands of
+    singleton communities) was a setup stall of minutes."""
     senders = np.asarray(senders, np.int64)
     receivers = np.asarray(receivers, np.int64)
-    paths: list = [[] for _ in range(n)]  # (node_id, size) chain per vertex
-
-    counter = [0]
-
-    def record(members: np.ndarray) -> None:
-        nid = counter[0]
-        counter[0] += 1
-        for v in members:
-            paths[v].append((nid, len(members)))
+    # column d = the depth-d ancestor (−1 below a vertex's leaf, padded
+    # with the leaf entry once the tree is built)
+    path_ids = np.full((n, max_depth + 1), -1, np.int64)
+    path_sizes = np.zeros((n, max_depth + 1), np.int64)
+    counter = 0
+    deepest = 0
 
     def rec(ls: np.ndarray, lr: np.ndarray, members: np.ndarray,
             depth: int) -> None:
-        record(members)
+        nonlocal counter, deepest
+        path_ids[members, depth] = counter
+        path_sizes[members, depth] = len(members)
+        counter += 1
+        if len(members):
+            deepest = max(deepest, depth)
         if len(members) <= min_size or depth >= max_depth:
             return
         sub = None
@@ -228,21 +250,28 @@ def build_dendrogram(senders: np.ndarray, receivers: np.ndarray, n: int,
                 sub = None
         if sub is None:
             sub = np.arange(len(members)) // max(min_size, len(members) // 2)
-        for c in range(int(sub.max()) + 1):
-            sel = sub == c
-            child = members[sel]
-            local = np.full(len(members), -1, np.int64)
-            local[sel] = np.arange(int(sel.sum()))
-            emask = sel[ls] & sel[lr]
-            rec(local[ls[emask]], local[lr[emask]], child, depth + 1)
+        n_sub = int(sub.max()) + 1
+        # members grouped by child, index order kept inside each child
+        order = np.argsort(sub, kind="stable")
+        m_lo = np.searchsorted(sub[order], np.arange(n_sub + 1))
+        local = np.empty(len(members), np.int64)
+        local[order] = np.arange(len(members)) - m_lo[sub[order]]
+        # edges with both ends in one child, grouped by child, edge order
+        # kept inside each child
+        es = sub[ls]
+        inside = np.flatnonzero(es == sub[lr])
+        inside = inside[np.argsort(es[inside], kind="stable")]
+        e_lo = np.searchsorted(es[inside], np.arange(n_sub + 1))
+        cs, cr = local[ls[inside]], local[lr[inside]]
+        for c in range(n_sub):
+            rec(cs[e_lo[c]:e_lo[c + 1]], cr[e_lo[c]:e_lo[c + 1]],
+                members[order[m_lo[c]:m_lo[c + 1]]], depth + 1)
 
     rec(senders, receivers, np.arange(n), 0)
-    depth = max(len(p) for p in paths) if paths else 1
-    path_ids = np.zeros((n, depth), np.int64)
-    path_sizes = np.zeros((n, depth), np.int64)
-    for v, chain in enumerate(paths):
-        for d in range(depth):
-            nid, sz = chain[min(d, len(chain) - 1)]
-            path_ids[v, d] = nid
-            path_sizes[v, d] = sz
+    path_ids = path_ids[:, :deepest + 1]
+    path_sizes = path_sizes[:, :deepest + 1]
+    for d in range(1, deepest + 1):
+        leaf = path_ids[:, d] < 0
+        path_ids[leaf, d] = path_ids[leaf, d - 1]
+        path_sizes[leaf, d] = path_sizes[leaf, d - 1]
     return Dendrogram(path_ids, path_sizes, len(senders))

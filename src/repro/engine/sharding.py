@@ -35,12 +35,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax ≥ 0.6 promoted shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 from repro.core.graph import DynamicGraph, PartitionedEdges
 from repro.core.gray import GRayResult, _bfs_reach_hops
@@ -123,7 +119,10 @@ class ShardedBankMatch:
         self.g_shards = g_shards
         devs = np.asarray(jax.devices()[:n_shards * g_shards])
         self.mesh = Mesh(devs.reshape(n_shards, g_shards), ("q", "g"))
-        self._fns = {}  # keyed (ell present, graph sharded, plan, part)
+        # keyed (ell pytree, graph sharded, plan, part pytree): the specs
+        # are built from the edge carriers' treedefs, whose static vertex
+        # count changes with every induced-subgraph bucket
+        self._fns = {}
 
     def _build(self, g: DynamicGraph, ell: Optional[EllGraph],
                part: Optional[PartitionedEdges], graph_sharded: bool,
@@ -162,7 +161,7 @@ class ShardedBankMatch:
 
         in_specs = (g_spec, rep, q, q) + extra_specs + bank_specs + plan_specs
         return jax.jit(shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+                                 out_specs=out_specs, check_vma=False))
 
     def __call__(self, g: DynamicGraph, r_lab: jnp.ndarray,
                  seed_ids: jnp.ndarray, seed_mask: jnp.ndarray,
@@ -175,8 +174,8 @@ class ShardedBankMatch:
         graph_sharded = graph_sharded and self.g_shards > 1
         if not graph_sharded:
             part = None  # partitioned slices only exist on the graph axis
-        key = (ell is not None, graph_sharded, row_node is not None,
-               part is not None)
+        key = (jax.tree.structure(ell), graph_sharded, row_node is not None,
+               jax.tree.structure(part))
         if key not in self._fns:
             self._fns[key] = self._build(g, ell, part, graph_sharded,
                                          row_node is not None)
@@ -268,7 +267,7 @@ class ShardedSweep:
             return jax.jit(shard_map(
                 f, mesh=self.mesh,
                 in_specs=self._specs(has_r0, ell, g, part=part),
-                out_specs=(_REP, _REP, _REP), check_rep=False))
+                out_specs=(_REP, _REP, _REP), check_vma=False))
 
         args = ((g,) + ((r0,) if has_r0 else ())
                 + ((ell,) if has_ell else ())
@@ -302,7 +301,7 @@ class ShardedSweep:
             return jax.jit(shard_map(
                 f, mesh=self.mesh,
                 in_specs=self._specs(has_r0, ell, g, e, part=part),
-                out_specs=(_REP, _REP, _REP), check_rep=False))
+                out_specs=(_REP, _REP, _REP), check_vma=False))
 
         args = ((g, e) + ((r0,) if has_r0 else ())
                 + ((ell,) if has_ell else ())
@@ -326,7 +325,7 @@ class ShardedSweep:
             return jax.jit(shard_map(
                 f, mesh=self.mesh,
                 in_specs=self._specs(False, ell, g, sources, part=part),
-                out_specs=_REP, check_rep=False))
+                out_specs=_REP, check_vma=False))
 
         args = ((g, sources) + ((ell,) if has_ell else ())
                 + ((part,) if has_part else ()))

@@ -1,9 +1,9 @@
-"""Public wrapper for the ELL SpMM Pallas kernel.
+"""Public wrapper for the ELL SpMM Pallas kernels.
 
-On CPU (this container) the kernel body executes under ``interpret=True``;
-on TPU the same call lowers to Mosaic. The wrapper finishes the two-phase
-reduction (segment-sum over split-row ids) and shards wide RHS batches so
-the VMEM residency bound on X holds.
+On the CPU backend the kernel body executes under ``interpret=True``;
+on TPU the same call lowers to Mosaic. The kernels reduce rows to vertices
+themselves; the wrapper chunks wide RHS batches so the VMEM-resident packed
+X and output blocks stay within their budget.
 """
 
 from __future__ import annotations
@@ -13,80 +13,71 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.spmv_ell.spmv_ell import ell_row_maxima, ell_row_partials
+from repro.kernels.spmv_ell.spmv_ell import (LANES, ell_vertex_maxima,
+                                             ell_vertex_sums, packed_bytes)
 from repro.sparse.ell import EllGraph
 
-_X_VMEM_BUDGET = 8 << 20  # bytes of VMEM granted to the resident X block
-_MIN_D_RESIDENT = 32      # legacy fixed bound — floor, so huge n never regresses
-_MAX_D_RESIDENT = 512
+# VMEM granted to the resident packed X and output blocks together (v5e:
+# 128 MiB of VMEM per core, 16 MiB scoped by default — the kernel raises
+# its own limit)
+_VMEM_BUDGET = 64 << 20
 
 
-def _d_resident(n: int) -> int:
-    """Widest RHS block whose (n, d) f32 residency fits the X budget.
-
-    The fixed 32-column bound assumed the 256k-vertex worst case; serving
-    banks sweep (n, B·k) blocks where small/medium graphs can keep far
-    wider blocks resident, and fewer kernel launches beat narrower tiles.
-    """
-    return int(max(_MIN_D_RESIDENT,
-                   min(_MAX_D_RESIDENT, _X_VMEM_BUDGET // max(4 * n, 1))))
+def resident_width(n_x: int, n: int) -> int:
+    """Columns of X one kernel launch carries: the widest pow-2 block
+    (≤ 128 lanes) whose packed ``(n_x, d)`` X and ``(n, d)`` output fit
+    the budget together. Every launch costs the same scalar work whatever
+    its width, so wider blocks mean fewer launches."""
+    d = LANES
+    while d > 1 and packed_bytes(n_x, d) + packed_bytes(n, d) > _VMEM_BUDGET:
+        d //= 2
+    return d
 
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@partial(jax.jit, static_argnames=("n", "block_rows"))
-def ell_spmm_kernel(cols: jnp.ndarray, vals: jnp.ndarray, mask: jnp.ndarray,
-                    row_ids: jnp.ndarray, x: jnp.ndarray, n: int,
-                    block_rows: int = 256) -> jnp.ndarray:
-    """y = A_ell @ x; x: (n, d) → y: (n, d)."""
+def _col_pass(kernel, x: jnp.ndarray, n: int, *ell) -> jnp.ndarray:
+    """Run a vertex kernel over X in resident-width column chunks. X has
+    all ``n_max`` rows even when the ELL tile is one graph shard's row
+    block (``n`` is then the shard's vertex count). Wide operands loop
+    over their chunks with one kernel call site (``lax.map``): every site
+    is a Mosaic compile, and full-graph G-Ray programs hold many sweeps."""
     interpret = _on_cpu()
-    d = x.shape[1]
-    d_res = _d_resident(n)
-    if d <= d_res:
-        partial_rows = ell_row_partials(cols, vals, mask, x,
-                                        block_rows=block_rows,
-                                        interpret=interpret)
-    else:  # shard the RHS batch to respect the VMEM bound on X
-        chunks = []
-        for lo in range(0, d, d_res):
-            chunks.append(ell_row_partials(
-                cols, vals, mask, x[:, lo:lo + d_res],
-                block_rows=block_rows, interpret=interpret))
-        partial_rows = jnp.concatenate(chunks, axis=1)
-    return jax.ops.segment_sum(partial_rows, row_ids, num_segments=n)
+    n_x, s = x.shape
+    d_res = resident_width(n_x, n)
+    if s <= d_res:
+        return kernel(*ell, x, n, interpret=interpret)
+    c = -(-s // d_res)
+    xs = jnp.pad(x, ((0, 0), (0, c * d_res - s)))
+    xs = xs.reshape(n_x, c, d_res).transpose(1, 0, 2)
+    ys = jax.lax.map(lambda xc: kernel(*ell, xc, n, interpret=interpret), xs)
+    return ys.transpose(1, 0, 2).reshape(n, c * d_res)[:, :s]
+
+
+@partial(jax.jit, static_argnames=("n",))
+def ell_spmm_kernel(cols: jnp.ndarray, vals: jnp.ndarray, mask: jnp.ndarray,
+                    row_ids: jnp.ndarray, x: jnp.ndarray,
+                    n: int) -> jnp.ndarray:
+    """y = A_ell @ x; x: (n, d) → y: (n, d)."""
+    return _col_pass(ell_vertex_sums, x, n, cols, vals, mask, row_ids)
 
 
 def ell_spmm_graph(g: EllGraph, x: jnp.ndarray) -> jnp.ndarray:
     return ell_spmm_kernel(g.cols, g.vals, g.mask, g.row_ids, x, g.n)
 
 
-@partial(jax.jit, static_argnames=("n", "block_rows"))
+@partial(jax.jit, static_argnames=("n",))
 def ell_reach_kernel(cols: jnp.ndarray, mask: jnp.ndarray,
-                     row_ids: jnp.ndarray, x: jnp.ndarray, n: int,
-                     block_rows: int = 256) -> jnp.ndarray:
+                     row_ids: jnp.ndarray, x: jnp.ndarray,
+                     n: int) -> jnp.ndarray:
     """y[v] = max_{u in N(v)} x[u] for indicator x ∈ [0,1]: (n, d) → (n, d).
 
     The max-plus sibling of ``ell_spmm_kernel`` — one bounded-BFS frontier
     sweep on the ELL layout. Vertices with no live in-arcs get 0.
     """
-    interpret = _on_cpu()
-    d = x.shape[1]
-    d_res = _d_resident(n)
-    if d <= d_res:
-        partial_rows = ell_row_maxima(cols, mask, x, block_rows=block_rows,
-                                      interpret=interpret)
-    else:
-        chunks = []
-        for lo in range(0, d, d_res):
-            chunks.append(ell_row_maxima(
-                cols, mask, x[:, lo:lo + d_res],
-                block_rows=block_rows, interpret=interpret))
-        partial_rows = jnp.concatenate(chunks, axis=1)
-    out = jax.ops.segment_max(partial_rows, row_ids, num_segments=n)
-    # segment_max fills vertices owning no row with -inf; reach wants 0
-    return jnp.maximum(out, 0.0)
+    return _col_pass(ell_vertex_maxima, x, n, cols, mask, row_ids)
 
 
 def ell_reach_graph(g: EllGraph, x: jnp.ndarray) -> jnp.ndarray:

@@ -1,18 +1,25 @@
 """Batched serving driver: prefill + decode loop with a KV cache
 (LM archs), batched scoring (BST), or the continuous multi-query
-pattern-match server (IGPM), on the reduced configs.
+pattern-match server (IGPM).
 
+IGPM runs its published (full) config and serves a Table III dataset twin
+at its published size (``--twin``, default ``transactions``); ``--smoke``
+picks the reduced config and a 1,024-vertex synthetic stream. The LM and
+BST drivers always run their reduced configs.
+
+  PYTHONPATH=src python -m repro.launch.serve --arch igpm-pem --steps 4
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --tokens 16
   PYTHONPATH=src python -m repro.launch.serve --arch bst
-  PYTHONPATH=src python -m repro.launch.serve --arch igpm-pem \\
+  PYTHONPATH=src python -m repro.launch.serve --arch igpm-pem --smoke \\
       --bank 8 --steps 12 --churn 0.25 --hotspot
-  PYTHONPATH=src python -m repro.launch.serve --arch igpm-pem \\
+  PYTHONPATH=src python -m repro.launch.serve --arch igpm-pem --smoke \\
       --async --scenario flash_crowd --rate 4000 --ticks 24
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -21,6 +28,7 @@ import numpy as np
 
 from repro.config.registry import get_arch, list_archs
 from repro.launch.cells import build_cell
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import TransformerLM
 
 
@@ -135,12 +143,14 @@ def _report_obs(server) -> None:
 
 def serve_igpm(arch, steps: int, bank: int, churn: float, hotspot: bool,
                policy_dir: str = "", register=(), retire=(),
-               obs=None) -> None:
-    """Continuous multi-query match serving on a synthetic churn stream.
+               obs=None, twin: str = "") -> None:
+    """Continuous multi-query match serving on a churn stream.
 
     One MatchServer serves a ``bank``-sized standing-query zoo against a
-    generated update stream (deletion traffic via ``--churn``, periodic
-    bursts via ``--hotspot``); per-step match deltas, per-bucket occupancy,
+    generated update stream — the ``twin`` Table III dataset twin at its
+    published size, or a 1,024-vertex synthetic stream when ``twin`` is
+    empty (deletion traffic via ``--churn``, periodic bursts via
+    ``--hotspot``); per-step match deltas, per-bucket occupancy,
     and the closing telemetry snapshot are printed. Scripted membership
     events exercise the engine's dynamic banks from the CLI:
 
@@ -154,7 +164,8 @@ def serve_igpm(arch, steps: int, bank: int, churn: float, hotspot: bool,
     """
     from repro.config.base import ObsConfig, ServingConfig
     from repro.core.query import clique4, query_zoo, square, star5, triangle
-    from repro.data.temporal import TemporalGraphSpec, generate_stream
+    from repro.data.temporal import (DATASET_TWINS, TemporalGraphSpec,
+                                     generate_stream)
     from repro.serving import MatchServer
 
     shapes = {"triangle": triangle, "square": square, "star5": star5,
@@ -162,12 +173,20 @@ def serve_igpm(arch, steps: int, bank: int, churn: float, hotspot: bool,
     membership = _parse_membership_events(register, retire)
 
     cfg = arch.model
-    n = min(cfg.n_max, 1024)
-    spec = TemporalGraphSpec("serve", "sparse_dense", n_vertices=n,
-                             n_edges=8 * n, n_steps=64, seed=0,
-                             churn=churn, hotspot=hotspot)
+    if twin:
+        spec = dataclasses.replace(DATASET_TWINS[twin], churn=churn,
+                                   hotspot=hotspot)
+    else:
+        n = min(cfg.n_max, 1024)
+        spec = TemporalGraphSpec("serve", "sparse_dense", n_vertices=n,
+                                 n_edges=8 * n, n_steps=64, seed=0,
+                                 churn=churn, hotspot=hotspot)
+    t0 = time.perf_counter()
     stream = generate_stream(spec, n_measured_steps=steps, u_max=512,
                              n_max=cfg.n_max, e_max=cfg.e_max)
+    print(f"[serve] stream {spec.name}: {spec.n_vertices} vertices, "
+          f"{stream.warmup_edges} warm-up edges under n_max={cfg.n_max} "
+          f"e_max={cfg.e_max} ({time.perf_counter() - t0:.1f}s)")
     server = MatchServer(cfg, query_zoo(bank),
                          ServingConfig(obs=obs or ObsConfig()), seed=0)
     print(f"[serve] buckets: {_occupancy_str(server)}")
@@ -221,7 +240,7 @@ def serve_igpm_async(arch, scenario: str, rate: float, ticks: int,
                      bank: int, sync_too: bool = False,
                      checkpoint_dir: str = "", obs=None,
                      control: str = "off", closed_loop: bool = False,
-                     control_episodes: int = 2) -> None:
+                     control_episodes: int = 2, twin: str = "") -> None:
     """Async serving runtime on a seeded workload scenario (DESIGN.md §6):
     a dedicated ingress thread replays the arrival process against the
     wall clock while the device-executor thread runs double-buffered
@@ -238,10 +257,15 @@ def serve_igpm_async(arch, scenario: str, rate: float, ticks: int,
     goodput/SLO-violation, DESIGN.md §9). ``--control train|frozen|off``
     attaches the RL serving controller: ``train`` learns during the run;
     ``frozen`` pre-trains ``--control-episodes`` closed-loop episodes,
-    freezes the policy, and measures pure greedy inference."""
+    freezes the policy, and measures pure greedy inference.
+
+    ``twin`` shapes the scenario's stream as that Table III dataset twin
+    at published size under the config's own capacities; empty keeps the
+    1,024-vertex synthetic stream sized to the workload."""
     from repro.config.base import (ControlConfig, ObsConfig, RuntimeConfig,
                                    ServingConfig)
     from repro.core.query import query_zoo
+    from repro.data.temporal import DATASET_TWINS
     from repro.runtime import (SCENARIOS, ServingRuntime, VirtualClock,
                                WallClock, build_workload, run_closed_loop,
                                run_workload_sync)
@@ -256,13 +280,17 @@ def serve_igpm_async(arch, scenario: str, rate: float, ticks: int,
     sc = SCENARIOS[scenario](rate=rate, tick_s=0.05, n_ticks=ticks,
                              n_vertices=min(arch.model.n_max, 1024), seed=0,
                              closed_loop=closed_loop)
-    wl = build_workload(sc, u_max=512)
-    print(f"[serve] scenario={scenario} rate={rate:.0f}/s "
-          f"ticks={ticks} events={wl.n_events} "
+    if twin:
+        cfg = arch.model
+        wl = build_workload(sc, n_max=cfg.n_max, e_max=cfg.e_max, u_max=512,
+                            twin=DATASET_TWINS[twin])
+    else:
+        wl = build_workload(sc, u_max=512)
+        cfg = dataclasses.replace(arch.model, n_max=wl.graph.n_max,
+                                  e_max=wl.graph.e_max)
+    print(f"[serve] scenario={scenario} stream={wl.spec.name} "
+          f"rate={rate:.0f}/s ticks={ticks} events={wl.n_events} "
           f"duration={sc.duration_s:.1f}s")
-    import dataclasses
-    cfg = dataclasses.replace(arch.model, n_max=wl.graph.n_max,
-                              e_max=wl.graph.e_max)
     serving = ServingConfig(microbatch_window=256, queue_depth=2048,
                             obs=obs or ObsConfig())
 
@@ -343,8 +371,19 @@ def serve_igpm_async(arch, scenario: str, rate: float, ticks: int,
 
 
 def main() -> None:
+    from repro.data.temporal import DATASET_TWINS
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
+    ap.add_argument("--smoke", action="store_true",
+                    help="igpm: run the reduced config on a 1,024-vertex "
+                         "synthetic stream instead of the published config "
+                         "on a dataset twin (the other archs always run "
+                         "their reduced configs)")
+    ap.add_argument("--twin", default="transactions",
+                    choices=sorted(DATASET_TWINS),
+                    help="igpm full config: the Table III dataset twin "
+                         "served at its published size")
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--steps", type=int, default=8,
                     help="igpm: serving steps to run")
@@ -415,7 +454,11 @@ def main() -> None:
                          "per-query freshness ledger and the health "
                          "watchdog (DESIGN.md §11)")
     args = ap.parse_args()
+    use_compile_cache()
     arch = get_arch(args.arch, smoke=True)
+    if arch.family == "igpm" and not args.smoke:
+        arch = get_arch(args.arch)
+    twin = "" if args.smoke else args.twin
     if arch.family == "lm":
         serve_lm(arch, args.tokens)
     elif arch.family == "recsys":
@@ -428,12 +471,13 @@ def main() -> None:
                              checkpoint_dir=args.checkpoint_dir, obs=obs,
                              control=args.control,
                              closed_loop=args.closed_loop,
-                             control_episodes=args.control_episodes)
+                             control_episodes=args.control_episodes,
+                             twin=twin)
         else:
             serve_igpm(arch, args.steps, args.bank, args.churn,
                        args.hotspot, policy_dir=args.policy_dir,
                        register=args.register, retire=args.retire,
-                       obs=obs)
+                       obs=obs, twin=twin)
     else:
         raise SystemExit(f"{args.arch} ({arch.family}) has no serve path")
 

@@ -28,8 +28,9 @@ PAPERS.md):
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
-from typing import Callable, Dict, List, NamedTuple
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -111,10 +112,16 @@ def _tick_rates(sc: ScenarioConfig) -> np.ndarray:
 
 def build_workload(sc: ScenarioConfig, n_max: int | None = None,
                    e_max: int | None = None,
-                   u_max: int = 512) -> Workload:
+                   u_max: int = 512,
+                   twin: Optional[TemporalGraphSpec] = None) -> Workload:
     """Materialize a scenario: seeded per-tick arrival counts, then the
     matching number of stream events (in stream order) dealt out tick by
-    tick. Everything downstream of the two seeds is deterministic."""
+    tick. Everything downstream of the two seeds is deterministic.
+
+    ``twin`` (a Table III dataset twin, ``repro.data.temporal``) supplies
+    the stream's shape — graph kind, vertex and edge counts, timesteps —
+    in place of the scenario's synthetic ``n_vertices`` graph; the
+    scenario still decides churn, hotspot bursts and arrivals."""
     rng = np.random.default_rng(sc.seed + 1)
     counts = rng.poisson(_tick_rates(sc)).astype(np.int64)
     need = int(counts.sum())
@@ -128,13 +135,21 @@ def build_workload(sc: ScenarioConfig, n_max: int | None = None,
     if churn > 0:
         per_step = min(per_step, int(u_max / (2.0 * churn)))
     n_meas = int(np.ceil(need_adds / max(per_step, 1))) + 1
-    n_edges = max(8 * sc.n_vertices, 4 * need_adds)
-    # keep edges_per_step ≥ the per-step cap so every measured batch is full
-    n_steps = max(4, n_edges // (2 * per_step))
-    spec = TemporalGraphSpec(
-        sc.name, sc.graph_kind, n_vertices=sc.n_vertices, n_edges=n_edges,
-        n_steps=n_steps, seed=sc.seed, churn=churn, hotspot=hotspot,
-        hotspot_period=1 if sc.kind == "flash_crowd" else 4)
+    hotspot_period = 1 if sc.kind == "flash_crowd" else 4
+    if twin is not None:
+        spec = dataclasses.replace(twin, name=f"{sc.name}@{twin.name}",
+                                   seed=sc.seed, churn=churn,
+                                   hotspot=hotspot,
+                                   hotspot_period=hotspot_period)
+    else:
+        n_edges = max(8 * sc.n_vertices, 4 * need_adds)
+        # keep edges_per_step ≥ the per-step cap so every measured batch
+        # is full
+        n_steps = max(4, n_edges // (2 * per_step))
+        spec = TemporalGraphSpec(
+            sc.name, sc.graph_kind, n_vertices=sc.n_vertices,
+            n_edges=n_edges, n_steps=n_steps, seed=sc.seed, churn=churn,
+            hotspot=hotspot, hotspot_period=hotspot_period)
     stream = generate_stream(spec, n_max=n_max, e_max=e_max,
                              n_measured_steps=n_meas, u_max=u_max)
     flat: List[UpdateEvent] = []

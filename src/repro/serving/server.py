@@ -65,6 +65,8 @@ class ServingStepStats:
     n_dropped: int = 0
     n_evicted: int = 0
     n_rejected: int = 0
+    # per-stage wall seconds of this step (tracing on; empty otherwise)
+    stage_s: Dict[str, float] = field(default_factory=dict)
 
     @property
     def n_new_patterns(self) -> int:
@@ -208,7 +210,8 @@ class MatchServer:
             n_pruned=out.n_pruned, ell_refresh_s=out.ell_refresh_s,
             subgraph_nodes=out.subgraph_nodes,
             subgraph_edges=out.subgraph_edges,
-            n_dropped=dropped, n_evicted=evicted, n_rejected=rejected)
+            n_dropped=dropped, n_evicted=evicted, n_rejected=rejected,
+            stage_s=dict(out.stage_s or {}))
         self.telemetry.record_step(st.total_s, n_events,
                                    st.n_new_patterns, out.frac_affected,
                                    n_dropped=dropped, n_evicted=evicted,

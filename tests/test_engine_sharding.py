@@ -50,26 +50,31 @@ def test_shard_count_pow2_and_capped():
 
 @pytest.mark.parametrize("backend", ["coo", "ell"])
 def test_bucket_sharded_match_equals_vmap(backend):
+    """One bucket matches graphs of two sizes, as consecutive induced
+    subgraphs of different capacity buckets do (the ELL mirror's static
+    vertex count changes between the calls)."""
     rng = np.random.default_rng(1)
-    n = 128
-    g = new_graph(n, 2048, labels=rng.integers(0, 4, n).astype(np.int32),
-                  senders=rng.integers(0, n, 500),
-                  receivers=rng.integers(0, n, 500))
     cfg = _cfg(backend)
     from repro.core.graph import ell_from_graph
-    ell = ell_from_graph(g, cfg.ell_width) if backend == "ell" else None
     sharded = QueryBucket(cfg, 8, 8, 4, shard="auto")
     plain = QueryBucket(cfg, 8, 8, 4, shard="off")
     assert sharded.n_shards > 1
     for i, q in enumerate(query_zoo(4)):
         sharded.register(f"q{i}", q)
         plain.register(f"q{i}", q)
-    r_lab = label_rwr(g, cfg.n_labels, iters=cfg.rwr_iters, ell=ell)
-    ra = sharded.match(g, r_lab, ell=ell)
-    rb = plain.match(g, r_lab, ell=ell)
-    for f in ra._fields:
-        np.testing.assert_array_equal(np.asarray(getattr(ra, f)),
-                                      np.asarray(getattr(rb, f)), err_msg=f)
+    for n in (128, 256):
+        g = new_graph(n, 2048,
+                      labels=rng.integers(0, 4, n).astype(np.int32),
+                      senders=rng.integers(0, n, 500),
+                      receivers=rng.integers(0, n, 500))
+        ell = ell_from_graph(g, cfg.ell_width) if backend == "ell" else None
+        r_lab = label_rwr(g, cfg.n_labels, iters=cfg.rwr_iters, ell=ell)
+        ra = sharded.match(g, r_lab, ell=ell)
+        rb = plain.match(g, r_lab, ell=ell)
+        for f in ra._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(ra, f)),
+                                          np.asarray(getattr(rb, f)),
+                                          err_msg=f"{f} at n={n}")
 
 
 @pytest.mark.parametrize("backend", ["coo", "ell"])

@@ -506,6 +506,7 @@ def test_partitioned_checkpoint_roundtrip_across_device_counts(tmp_path):
             pickle.dump([dict(s._patterns) for s in srv.stores], f)
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = "src" + (
         ":" + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
