@@ -1,0 +1,5 @@
+"""The reading of ``ell_vertex_maxima_roofline.steady`` over a backlog cell's window."""
+
+from bench.measures import load_reader
+
+read = load_reader("ell_vertex_maxima_roofline.steady")
