@@ -304,9 +304,10 @@ class ObsConfig:
     document Perfetto / chrome://tracing opens directly). The flight
     recorder keeps the last ``flight_n`` fully-traced steps and dumps
     them to ``<flight_path>.NNN.jsonl`` on demand, on executor crash, or
-    when an e2e latency sample exceeds ``slo_e2e_ms``. ``profiler_dir``
-    brackets steps ``[profile_start, profile_stop)`` in a
-    ``jax.profiler`` trace session for device-level drill-down.
+    when an e2e latency sample exceeds ``slo_e2e_ms``. Enabled tracing
+    also counts each step's host-link bytes and compiles, spans compiles
+    and generation-2 collections, and annotates every span into any
+    running ``jax.profiler`` capture.
 
     ``freshness=True`` attaches a per-standing-query
     :class:`~repro.obs.freshness.FreshnessLedger` (DESIGN.md §11) to the
@@ -328,9 +329,6 @@ class ObsConfig:
     flight_path: str = ""      # dump prefix; "" = in-memory only
     slo_e2e_ms: float = 0.0    # >0: dump flight when an e2e sample exceeds
     prometheus_path: str = ""  # Prometheus text-format snapshot target
-    profiler_dir: str = ""     # jax.profiler trace dir ("" = off)
-    profile_start: int = 0     # first step inside the profiler session
-    profile_stop: int = 0      # first step outside it
     # -- per-query freshness (DESIGN.md §11) --
     freshness: bool = False        # per-standing-query staleness ledger
     freshness_slo_s: float = 0.5   # staleness SLO the burn windows track

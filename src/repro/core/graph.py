@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import to_host, uploaded
 from repro.sparse.ell import (EllGraph, build_ell, build_ell_sharded,
                               ell_block_capacity, ell_row_capacity)
 
@@ -417,9 +418,9 @@ class EdgePartition:
 
     def rebuild(self, g: DynamicGraph) -> None:
         """Compact host+device slices from the live edge set of ``g``."""
-        em = np.asarray(g.edge_mask)
-        s = np.asarray(g.senders)
-        r = np.asarray(g.receivers)
+        em = to_host(g.edge_mask)
+        s = to_host(g.senders)
+        r = to_host(g.receivers)
         cap = self.e_cap_slice
         send = np.zeros((self.n_shards, cap), np.int32)
         recv = np.zeros((self.n_shards, cap), np.int32)
@@ -437,10 +438,9 @@ class EdgePartition:
             self._fill.append(len(idx))
             self._live.append(len(idx))
         self._send_h, self._recv_h, self._mask_h = send, recv, mask
-        self._send_d = jnp.asarray(send)
-        self._recv_d = jnp.asarray(recv)
-        self._mask_d = jnp.asarray(mask)
-        self._cursor = int(np.asarray(g.n_edges))
+        self._send_d, self._recv_d, self._mask_d = uploaded(
+            (jnp.asarray(send), jnp.asarray(recv), jnp.asarray(mask)))
+        self._cursor = int(to_host(g.n_edges))
         self._last = g
         self.n_rebuilds += 1
 
@@ -466,9 +466,9 @@ class EdgePartition:
             self.rebuild(g)
         touched: Set[Tuple[int, int]] = set()
         dirty: Set[int] = set()  # compacted slices → full-row upload
-        add_src = np.asarray(upd.add_src)
-        add_dst = np.asarray(upd.add_dst)
-        add_mask = np.asarray(upd.add_mask)
+        add_src = to_host(upd.add_src)
+        add_dst = to_host(upd.add_dst)
+        add_mask = to_host(upd.add_mask)
         slot = self._cursor
         for u, v, m in zip(add_src, add_dst, add_mask):
             if not m:
@@ -491,9 +491,9 @@ class EdgePartition:
             slot += 1
         self._cursor += int(add_mask.sum())
 
-        rem_src = np.asarray(upd.rem_src)
-        rem_dst = np.asarray(upd.rem_dst)
-        rem_mask = np.asarray(upd.rem_mask)
+        rem_src = to_host(upd.rem_src)
+        rem_dst = to_host(upd.rem_dst)
+        rem_mask = to_host(upd.rem_mask)
         for u, v, m in zip(rem_src, rem_dst, rem_mask):
             if not (m and 0 <= v < self.n_max):
                 continue
@@ -516,17 +516,20 @@ class EdgePartition:
         padded index vectors, as the ELL mirror does); compacted slices
         upload as full rows."""
         for d in sorted(dirty):
-            self._send_d = self._send_d.at[d].set(jnp.asarray(self._send_h[d]))
-            self._recv_d = self._recv_d.at[d].set(jnp.asarray(self._recv_h[d]))
-            self._mask_d = self._mask_d.at[d].set(jnp.asarray(self._mask_h[d]))
+            self._send_d = self._send_d.at[d].set(
+                uploaded(jnp.asarray(self._send_h[d])))
+            self._recv_d = self._recv_d.at[d].set(
+                uploaded(jnp.asarray(self._recv_h[d])))
+            self._mask_d = self._mask_d.at[d].set(
+                uploaded(jnp.asarray(self._mask_h[d])))
         touched = {(d, j) for d, j in touched if d not in dirty}
         if not touched:
             return
 
         def _pad(a: np.ndarray, fill) -> jnp.ndarray:
             width = max(1, 1 << int(np.ceil(np.log2(max(len(a), 1)))))
-            return jnp.asarray(np.concatenate(
-                [a, np.full(width - len(a), fill, a.dtype)]))
+            return uploaded(jnp.asarray(np.concatenate(
+                [a, np.full(width - len(a), fill, a.dtype)])))
 
         dj = np.asarray(sorted(touched), np.int32)
         dd = _pad(dj[:, 0], self.n_shards)  # pad rows → OOB, dropped
@@ -572,9 +575,9 @@ def ell_from_graph(g: DynamicGraph, k: int,
     of the graph mesh axis instead (``build_ell_sharded`` — per-slice row
     blocks, local ``row_ids``, ``r_cap`` then caps one block).
     """
-    em = np.asarray(g.edge_mask)
-    s = np.asarray(g.senders)[em]
-    r = np.asarray(g.receivers)[em]
+    em = to_host(g.edge_mask)
+    s = to_host(g.senders)[em]
+    r = to_host(g.receivers)[em]
     if n_shards > 1:
         if r_cap is None:
             r_cap = ell_block_capacity(g.n_max, g.e_max, k, n_shards)
@@ -651,9 +654,9 @@ class EllCache:
 
     def rebuild(self, g: DynamicGraph) -> None:
         """Compact host+device state from the live edge set of ``g``."""
-        em = np.asarray(g.edge_mask)
-        s = np.asarray(g.senders)[em]
-        r = np.asarray(g.receivers)[em]
+        em = to_host(g.edge_mask)
+        s = to_host(g.senders)[em]
+        r = to_host(g.receivers)[em]
         n, k = self.n_max, self.k
         deg_in = np.bincount(r, minlength=n)
         rows_per_v = np.maximum(1, -(-deg_in // k))
@@ -682,7 +685,7 @@ class EllCache:
             list(range(start_v[v], start_v[v] + rows_per_v[v]))
             for v in range(n)]
         self._fill = deg_in.astype(np.int64)
-        self._cursor = int(np.asarray(g.n_edges))
+        self._cursor = int(to_host(g.n_edges))
 
         cols = np.zeros((self.r_cap, k), np.int32)
         mask = np.zeros((self.r_cap, k), bool)
@@ -695,9 +698,8 @@ class EllCache:
         cols[start_v[rs] + pos // k, pos % k] = ss
         mask[start_v[rs] + pos // k, pos % k] = True
         self._cols_h, self._mask_h, self._row_ids_h = cols, mask, row_ids
-        self._cols_d = jnp.asarray(cols)
-        self._mask_d = jnp.asarray(mask)
-        self._row_ids_d = jnp.asarray(row_ids)
+        self._cols_d, self._mask_d, self._row_ids_d = uploaded(
+            (jnp.asarray(cols), jnp.asarray(mask), jnp.asarray(row_ids)))
         self._last = g
         self.n_rebuilds += 1
 
@@ -769,9 +771,9 @@ class EllCache:
         touched: set = set()
         new_rows: set = set()
         overflow = False
-        add_src = np.asarray(upd.add_src)
-        add_dst = np.asarray(upd.add_dst)
-        add_mask = np.asarray(upd.add_mask)
+        add_src = to_host(upd.add_src)
+        add_dst = to_host(upd.add_dst)
+        add_mask = to_host(upd.add_mask)
         slot = self._cursor
         for u, v, m in zip(add_src, add_dst, add_mask):
             if not m:
@@ -783,9 +785,9 @@ class EllCache:
             slot += 1
         self._cursor += int(add_mask.sum())
         if not overflow:
-            rem_src = np.asarray(upd.rem_src)
-            rem_dst = np.asarray(upd.rem_dst)
-            rem_mask = np.asarray(upd.rem_mask)
+            rem_src = to_host(upd.rem_src)
+            rem_dst = to_host(upd.rem_dst)
+            rem_mask = to_host(upd.rem_mask)
             for u, v, m in zip(rem_src, rem_dst, rem_mask):
                 if m and 0 <= v < self.n_max:
                     self._remove(int(u), int(v), touched)
@@ -806,8 +808,8 @@ class EllCache:
         """
         def _pad(a: np.ndarray, fill: int) -> jnp.ndarray:
             width = max(1, 1 << int(np.ceil(np.log2(max(len(a), 1)))))
-            return jnp.asarray(np.concatenate(
-                [a, np.full(width - len(a), fill, a.dtype)]))
+            return uploaded(jnp.asarray(np.concatenate(
+                [a, np.full(width - len(a), fill, a.dtype)])))
 
         if touched:
             rc = np.asarray(sorted(touched), np.int32)

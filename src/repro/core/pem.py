@@ -28,6 +28,7 @@ from repro.config.base import IGPMConfig
 from repro.core.dqn import DQNAgent, Transition
 from repro.core.graph import DynamicGraph
 from repro.core.louvain import Dendrogram, build_dendrogram
+from repro.obs.trace import to_host
 
 
 class PartialExecutionManager:
@@ -67,13 +68,13 @@ class PartialExecutionManager:
         The split dendrogram is rebuilt only when the live edge count grows
         past ``recluster_growth``; any threshold is then an O(n·depth) cut.
         """
-        n_live_edges = int(np.asarray(g.edge_mask).sum())
+        n_live_edges = int(to_host(g.edge_mask).sum())
         if (self._dendro is None
                 or n_live_edges > self._dendro.n_edges_at_build
                 * (1 + self.recluster_growth)):
-            s = np.asarray(g.senders)
-            r = np.asarray(g.receivers)
-            em = np.asarray(g.edge_mask)
+            s = to_host(g.senders)
+            r = to_host(g.receivers)
+            em = to_host(g.edge_mask)
             t0 = time.perf_counter()
             self._dendro = build_dendrogram(
                 s[em], r[em], g.n_max,
@@ -100,15 +101,15 @@ class PartialExecutionManager:
         if len(updated) == 0:
             return np.zeros(g.n_max, bool), 0.0
         touched = np.unique(comm[updated])
-        mask = np.isin(comm, touched) & np.asarray(g.node_mask)
+        mask = np.isin(comm, touched) & to_host(g.node_mask)
         frac = len(touched) / max(n_comm, 1)
         return mask, frac
 
     # -- RL feedback loop (paper Fig. 3 lines 7-12) ---------------------------
 
     def observation(self, g: DynamicGraph, frac_affected: float) -> np.ndarray:
-        n_nodes = max(float(np.asarray(g.node_mask).sum()), 1.0)
-        n_edges = float(np.asarray(g.edge_mask).sum())
+        n_nodes = max(float(to_host(g.node_mask).sum()), 1.0)
+        n_edges = float(to_host(g.edge_mask).sum())
         density = n_edges / n_nodes
         return np.array([density / 10.0, frac_affected], np.float32)
 

@@ -41,13 +41,14 @@ from repro.core.graph import (DynamicGraph, EdgePartition, EllCache,
 from repro.core.pem import PartialExecutionManager
 from repro.core.query import DagFull, Query, query_signature
 from repro.core.rwr import label_rwr, label_rwr_adaptive
-from repro.core.subgraph import extract_induced, remap_matched
+from repro.core.subgraph import (induced_tile, pull_graph, remap_matched,
+                                  select_induced, upload_induced)
 from repro.engine.buckets import (QueryBucket, _pow2, bucket_shape,
                                   decode_strings, encode_strings)
 from repro.engine.sharding import ShardedSweep, device_split
 from repro.engine.state import EngineState, QueryDelta, StepOutput
 from repro.engine.store import PatternStore, live_vertex_mask
-from repro.obs import Obs
+from repro.obs import Obs, StepScope, to_host, uploaded
 
 
 class Engine:
@@ -407,22 +408,31 @@ class Engine:
         ELL cache and/or the edge-partition router — DESIGN.md §10).
 
         The returned refresh time covers only the mirror maintenance — the
-        COO ``apply_update`` is paid identically by all backends."""
+        COO ``apply_update`` is paid identically by all backends. Child
+        spans of ``engine/apply``: ``ell_rebuild`` (a mirror resync after
+        a graph swap), ``update`` (the COO dispatch, removal path
+        included) and ``ell_refresh`` (the refresh time returned)."""
+        obs = self.obs
         mirrors = [m for m in (self.ell_cache, self.part_cache)
                    if m is not None]
         if not mirrors:
-            return apply_update(g, upd), 0.0
+            with obs.span("engine/apply/update"):
+                return apply_update(g, upd), 0.0
         for m in mirrors:
             if m._last is not g:
-                m.rebuild(g)
-        g2 = apply_update(g, upd)
-        t0 = time.perf_counter()
-        for m in mirrors:
-            m.refresh(g, g2, upd)
-        jax.block_until_ready(self.ell_cache._cols_d
-                              if self.ell_cache is not None
-                              else self.part_cache._send_d)
-        return g2, time.perf_counter() - t0
+                with obs.span("engine/apply/ell_rebuild"):
+                    m.rebuild(g)
+        with obs.span("engine/apply/update"):
+            g2 = apply_update(g, upd)
+        with obs.span("engine/apply/ell_refresh"):
+            t0 = time.perf_counter()
+            for m in mirrors:
+                m.refresh(g, g2, upd)
+            jax.block_until_ready(self.ell_cache._cols_d
+                                  if self.ell_cache is not None
+                                  else self.part_cache._send_d)
+            refresh_s = time.perf_counter() - t0
+        return g2, refresh_s
 
     @property
     def _full_ell(self):
@@ -480,17 +490,17 @@ class Engine:
             r, n, skipped = self._sweeps.label_table(
                 g, cfg.n_labels, iters, cfg.restart_prob, r0, ell,
                 tol=cfg.rwr_tol, part=part)
-            self._account_sweeps(int(n), int(skipped))
+            self._account_sweeps(int(to_host(n)), int(to_host(skipped)))
             # decommit from the sweep mesh: bucket meshes may span a
             # different device set, and multi-device-committed inputs do
             # not transfer implicitly. The (n, L) table is tiny next to
             # the sweeps it took to produce.
-            return jnp.asarray(np.asarray(r))
+            return uploaded(jnp.asarray(to_host(r)))
         if cfg.rwr_tol > 0:
             r, n, skipped = label_rwr_adaptive(
                 g, cfg.n_labels, max_iters=iters, tol=cfg.rwr_tol,
                 c=cfg.restart_prob, r0=r0, ell=ell)
-            self._account_sweeps(int(n), int(skipped))
+            self._account_sweeps(int(to_host(n)), int(to_host(skipped)))
             return r
         self._account_sweeps(iters, 0)
         return label_rwr(g, cfg.n_labels, iters=iters,
@@ -523,14 +533,14 @@ class Engine:
             with obs.span("engine/merge/bucket",
                           bucket=f"{shape[0]}x{shape[1]}",
                           rows=bucket.n_live):
-                matched = np.asarray(res.matched)
+                matched = to_host(res.matched)
                 if remap is not None:
                     matched = remap_matched(
                         matched.reshape(-1, matched.shape[-1]),
                         remap).reshape(matched.shape)
-                goodness = np.asarray(res.goodness)
-                exact = np.asarray(res.exact)
-                valid = np.asarray(res.valid)
+                goodness = to_host(res.goodness)
+                exact = to_host(res.exact)
+                valid = to_host(res.valid)
                 for slot, qid in bucket.rows():
                     # one device row serves its whole duplicate group: the
                     # primary (owning the row) plus every alias store
@@ -650,9 +660,9 @@ class Engine:
 
 def _n_events(upd: UpdateBatch) -> int:
     """Masked update entries in a batch (host-side; staleness accounting)."""
-    return int(np.asarray(upd.add_mask).sum()
-               + np.asarray(upd.rem_mask).sum()
-               + np.asarray(upd.lab_mask).sum())
+    return int(to_host(upd.add_mask).sum()
+               + to_host(upd.rem_mask).sum()
+               + to_host(upd.lab_mask).sum())
 
 
 def engine_step(eng: Engine, state: EngineState,
@@ -666,15 +676,17 @@ def engine_step(eng: Engine, state: EngineState,
     bitwise/trace-count tests pin). With tracing on, the step runs inside
     a step-scoped trace context (every span carries ``step``), the flight
     recorder captures the step's span group, and per-stage wall times
-    come back in ``StepOutput.stage_s``."""
+    come back in ``StepOutput.stage_s``, and the bytes it moved across
+    the host link and the programs it compiled in ``StepOutput.counts``
+    (the step's :class:`~repro.obs.StepScope`)."""
     obs = eng.obs
     if not obs.enabled:
         return _engine_step(eng, state, upd, obs, None)
     step_idx = int(state.step_idx)
-    with obs.profile_step(step_idx), obs.context(step=step_idx):
-        obs.begin_step(step_idx)
+    with obs.context(step=step_idx):
+        scope = obs.begin_step(step_idx)
         try:
-            return _engine_step(eng, state, upd, obs, {})
+            return _engine_step(eng, state, upd, obs, scope)
         finally:
             obs.end_step(step_idx)
 
@@ -731,27 +743,30 @@ def _run_matches(eng: Engine, jobs, obs: Obs, tracing: bool):
 
 
 def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
-                 obs: Obs, stage: Optional[Dict[str, float]]
+                 obs: Obs, scope: Optional[StepScope]
                  ) -> Tuple[EngineState, StepOutput]:
-    """Pipeline body. ``stage`` is None when tracing is disabled (all
-    span calls then hit the shared no-op span); when tracing, it
-    accumulates per-stage seconds for ``StepOutput.stage_s``. Stage
-    taxonomy (DESIGN.md §8): apply → prune → pem → [storm: rwr → seeds →
-    gray | induced: extract → rwr → gray] → device_wait → merge →
+    """Pipeline body. ``scope`` is None when tracing is disabled (all
+    span calls then hit the shared no-op span); when tracing, its
+    ``stage`` accumulates per-stage seconds for ``StepOutput.stage_s``.
+    Stage taxonomy (DESIGN.md §8): apply → prune → pem → [storm: rwr →
+    seeds → gray | induced: extract → rwr → gray] → device_wait → merge →
     feedback. Extra ``block_until_ready`` fences that split host
     dispatch from device wait run ONLY under ``obs.enabled``."""
     cfg, ecfg = eng.cfg, eng.ecfg
+    stage = None if scope is None else scope.stage
     tracing = stage is not None
     with obs.span("engine/apply") as sp:
         g, refresh_s = eng._apply(state.graph, upd)
-        n_events = _n_events(upd)
-        rlab_events = state.rlab_events + n_events
-        rlab_version = state.rlab_version
-        upd_ids = None
-        if ecfg.mode != "batch":
-            ids, mask = updated_vertices(g, upd, ecfg.v_max)
-            upd_ids = np.asarray(jnp.where(mask, ids, -1))
-        jax.block_until_ready(g)
+        with obs.span("engine/apply/updated"):
+            n_events = _n_events(upd)
+            rlab_events = state.rlab_events + n_events
+            rlab_version = state.rlab_version
+            upd_ids = None
+            if ecfg.mode != "batch":
+                ids, mask = updated_vertices(g, upd, ecfg.v_max)
+                upd_ids = to_host(jnp.where(mask, ids, -1))
+        with obs.span("engine/apply/wait"):
+            jax.block_until_ready(g)
     if tracing:
         stage["apply"] = sp.dur_s
         stage["ell_refresh"] = refresh_s
@@ -760,7 +775,7 @@ def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
     n_pruned = 0
     if (ecfg.mode != "batch"
             and any(s.total for s in eng.stores.values())
-            and bool(np.asarray(upd.rem_mask).any())):
+            and bool(to_host(upd.rem_mask).any())):
         with obs.span("engine/prune") as sp:
             live = live_vertex_mask(g)
             # prune each DISTINCT pattern dict once (alias stores share
@@ -776,7 +791,7 @@ def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
             stage["prune"] = sp.dur_s
 
     t0 = time.perf_counter()
-    n_live = max(int(np.asarray(g.node_mask).sum()), 1)
+    n_live = max(int(to_host(g.node_mask).sum()), 1)
     rlab_hit = seed_hit = False
     community = 0
     rl_loss = 0.0
@@ -864,7 +879,7 @@ def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
                 rlab_events = 0
                 rlab_version += 1
                 eng.rlab_misses += 1
-            sf = jnp.asarray(rec_mask)
+            sf = uploaded(jnp.asarray(rec_mask))
             mask_arr = np.asarray(rec_mask, bool)
             jobs = []
             bucket_hits = []
@@ -916,15 +931,22 @@ def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
                             rows=eng.last_merge_rows,
                             stores=eng.last_merge_stores,
                             folds=eng.last_merge_folds)
-            sub_n, sub_e = n_live, int(np.asarray(g.edge_mask).sum())
+            sub_n, sub_e = n_live, int(to_host(g.edge_mask).sum())
         else:
             with obs.span("engine/extract") as sp:
-                sub = extract_induced(
-                    g, rec_mask,
-                    ell_k=(cfg.ell_width if eng.ell_cache is not None
-                           else None))
+                with obs.span("engine/extract/pull") as sp_pull:
+                    host = pull_graph(g)
+                with obs.span("engine/extract/select"):
+                    sel = select_induced(host, rec_mask)
+                tile = None
+                if eng.ell_cache is not None:
+                    with obs.span("engine/extract/ell"):
+                        tile = induced_tile(sel, cfg.ell_width)
+                with obs.span("engine/extract/upload"):
+                    sub = upload_induced(sel, tile)
             if tracing:
                 stage["extract"] = sp.dur_s
+                stage["extract_pull"] = sp_pull.dur_s
             with obs.span("engine/rwr", mode="induced") as sp:
                 r_sub = eng._label_table(sub.graph, ell=sub.ell)
                 if tracing:
@@ -969,5 +991,5 @@ def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
         rlab_cache_hit=rlab_hit, seed_cache_hit=seed_hit,
         rwr_sweeps=eng._last_sweeps,
         rwr_cols_skipped=eng._last_cols_skipped, deltas=deltas,
-        stage_s=stage)
+        stage_s=stage, counts=None if scope is None else scope.counts)
     return new_state, out

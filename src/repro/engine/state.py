@@ -74,10 +74,16 @@ class StepOutput(NamedTuple):
     rwr_cols_skipped: int = 0  # converged-column sweeps retired (adaptive)
     deltas: Tuple[QueryDelta, ...] = ()
     # per-stage wall seconds (DESIGN.md §8) — None unless tracing is on;
-    # keys: apply/ell_refresh/prune/pem/extract/rwr/seeds/gray/
-    # device_wait/merge/feedback. The serving layer feeds these into
-    # ``stage_*`` telemetry channels.
+    # keys: apply/ell_refresh/prune/pem/extract/extract_pull/rwr/seeds/
+    # gray/device_wait/merge/feedback, plus compile and gc (seconds of
+    # the step spent compiling or loading programs, and in generation-2
+    # collections). The serving layer feeds these into ``stage_*``
+    # telemetry channels.
     stage_s: Optional[Dict[str, float]] = None
+    # what the step moved and built (tracing on; None otherwise):
+    # d2h_bytes / h2d_bytes across the host link, each device array
+    # once; compiles = backend compiles or cache loads
+    counts: Optional[Dict[str, int]] = None
 
     @property
     def n_new_patterns(self) -> int:

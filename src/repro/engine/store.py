@@ -17,6 +17,7 @@ from typing import Dict, Tuple
 import numpy as np
 
 from repro.core.graph import DynamicGraph
+from repro.obs.trace import to_host
 
 
 class PatternStore:
@@ -117,8 +118,8 @@ class PatternStore:
 
 def live_vertex_mask(g: DynamicGraph) -> np.ndarray:
     """Vertices incident to at least one live arc (host-side)."""
-    em = np.asarray(g.edge_mask)
+    em = to_host(g.edge_mask)
     live = np.zeros(g.n_max, bool)
-    live[np.asarray(g.senders)[em]] = True
-    live[np.asarray(g.receivers)[em]] = True
-    return live & np.asarray(g.node_mask)
+    live[to_host(g.senders)[em]] = True
+    live[to_host(g.receivers)[em]] = True
+    return live & to_host(g.node_mask)

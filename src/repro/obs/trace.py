@@ -13,20 +13,40 @@ every span emitted inside inherits into ``args``, which is what lets a
 post-mortem follow one micro-batch offer→delta across the ingress and
 executor threads.
 
+The enabled tracer also opens a ``jax.profiler.TraceAnnotation`` per
+span (name only), so a profiler capture shows the program's spans on
+their thread's host line above the device ops.
+
+A traced step owns a :class:`StepScope` bound to the calling thread
+(``begin_step`` → ``take_step``). Three things fill it besides the
+engine's own stage timers: :func:`to_host` / :func:`uploaded` count the
+bytes the step moves across the host link; a process-wide
+``jax.monitoring`` listener turns every trace, lowering and backend
+compile (or cache load) into an ``engine/compile`` span; a
+``gc.callbacks`` hook turns every generation-2 collection into an
+``engine/gc`` span. Both hooks are registered once, while at least one
+enabled hub lives, and route each event to the step of the thread it
+fired on (outside a step: to the newest enabled hub, span only).
+
 Zero-cost disabled: ``Obs(ObsConfig())`` wires the :data:`NULL_TRACER`
-singleton whose ``span()`` returns a shared no-op context manager, and
-every *extra* device fence in the engine sits behind ``if obs.enabled``.
-The disabled path is pinned bitwise + by compiled-trace-count in
-``tests/test_obs.py``.
+singleton whose ``span()`` returns a shared no-op context manager,
+registers no hook, and every *extra* device fence in the engine sits
+behind ``if obs.enabled``. The disabled path is pinned bitwise + by
+compiled-trace-count in ``tests/test_obs.py``.
 """
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
+import weakref
 from collections import deque
-from contextlib import contextmanager
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
+
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config.base import ObsConfig
 from repro.obs import export as _export
@@ -50,7 +70,7 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "dur_s")
+    __slots__ = ("_tracer", "name", "args", "_t0", "dur_s", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -58,16 +78,62 @@ class _Span:
         self.args = args
         self._t0 = 0.0
         self.dur_s = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
         self.dur_s = t1 - self._t0
         self._tracer._emit(self.name, "X", self._t0, t1, self.args)
         return False
+
+
+class StepScope:
+    """What one traced step measured beyond its stage spans: ``stage``
+    (the engine's per-stage seconds, plus ``compile`` and ``gc`` filled by
+    the hooks) and ``counts`` (``d2h_bytes``, ``h2d_bytes``, backend
+    ``compiles``). Each device array counts once per step, the first time
+    the host reads it: jax keeps the host copy, so later reads move
+    nothing."""
+
+    __slots__ = ("tracer", "stage", "counts", "_seen", "_compile_iv")
+
+    def __init__(self, tracer: "Tracer"):
+        self.tracer = tracer
+        self.stage: Dict[str, float] = {"compile": 0.0, "gc": 0.0}
+        self.counts: Dict[str, int] = {"d2h_bytes": 0, "h2d_bytes": 0,
+                                       "compiles": 0}
+        # id → array, held until the step ends so an id is never reused
+        self._seen: Dict[int, Any] = {}
+        # disjoint compile intervals: nested events (a trace inside a
+        # trace) add only the time no earlier event of the step covered
+        self._compile_iv: List[Tuple[float, float]] = []
+
+    def pulled(self, x: Any) -> None:
+        if isinstance(x, jax.Array) and id(x) not in self._seen:
+            self._seen[id(x)] = x
+            self.counts["d2h_bytes"] += int(x.nbytes)
+
+    def add_compile(self, t0: float, t1: float, backend: bool) -> None:
+        covered = sum(max(0.0, min(t1, b) - max(t0, a))
+                      for a, b in self._compile_iv)
+        self.stage["compile"] += max(t1 - t0 - covered, 0.0)
+        keep = []
+        for a, b in self._compile_iv:
+            if b < t0 or a > t1:
+                keep.append((a, b))
+            else:
+                t0, t1 = min(t0, a), max(t1, b)
+        keep.append((t0, t1))
+        self._compile_iv = keep
+        if backend:
+            self.counts["compiles"] += 1
 
 
 class _Ctx:
@@ -123,12 +189,20 @@ class Tracer:
 
     # -- step grouping (flight recorder) ---------------------------------
 
-    def begin_step(self, step: int) -> None:
+    def begin_step(self, step: int) -> StepScope:
+        """Start grouping the calling thread's spans into a step, and
+        bind the step's :class:`StepScope` to the thread."""
         self._tls.step_events = []
+        scope = StepScope(self)
+        _THREAD.scope = scope
+        return scope
 
     def take_step(self) -> List[Dict[str, Any]]:
         events = getattr(self._tls, "step_events", None) or []
         self._tls.step_events = None
+        scope = getattr(_THREAD, "scope", None)
+        if scope is not None and scope.tracer is self:
+            _THREAD.scope = None
         return events
 
     # -- emission --------------------------------------------------------
@@ -206,6 +280,136 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+# -- process-wide hooks: host-link bytes, compiles, collections ---------------
+
+# the traced step the calling thread is in (a StepScope), if any
+_THREAD = threading.local()
+# weak references to the live enabled tracers, oldest first; the hooks are
+# registered exactly while this is non-empty
+_HUBS: List["weakref.ref[Tracer]"] = []
+_HOOKS_LOCK = threading.RLock()
+_hooked = False
+
+# jax.monitoring duration events of a jit's way to an executable
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # a backend compile, or a load of the program from the persistent cache
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+
+def to_host(x: Any, dtype: Any = None) -> np.ndarray:
+    """``np.asarray(x[, dtype])``, counted as ``d2h_bytes`` of the calling
+    thread's traced step when ``x`` is a device array (once per array and
+    step). Without an enabled hub this is ``np.asarray`` alone."""
+    if _HUBS:
+        scope = getattr(_THREAD, "scope", None)
+        if scope is not None:
+            scope.pulled(x)
+    return np.asarray(x) if dtype is None else np.asarray(x, dtype)
+
+
+def uploaded(tree: Any) -> Any:
+    """Count the device arrays of ``tree`` (just made from host data) as
+    ``h2d_bytes`` of the calling thread's traced step; returns ``tree``."""
+    if _HUBS:
+        scope = getattr(_THREAD, "scope", None)
+        if scope is not None:
+            scope.counts["h2d_bytes"] += sum(
+                int(a.nbytes) for a in jax.tree.leaves(tree)
+                if isinstance(a, jax.Array))
+    return tree
+
+
+def _route() -> Tuple[Optional[Tracer], Optional[StepScope]]:
+    """The calling thread's traced step and its tracer; outside a step,
+    the newest live tracer and no step."""
+    scope = getattr(_THREAD, "scope", None)
+    if scope is not None:
+        return scope.tracer, scope
+    for ref in reversed(_HUBS):
+        tracer = ref()
+        if tracer is not None:
+            return tracer, None
+    return None, None
+
+
+def _on_duration(event: str, duration: float, **kw: Any) -> None:
+    phase = COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    tracer, scope = _route()
+    if tracer is None:
+        return
+    t1 = time.perf_counter()
+    t0 = t1 - duration
+    tracer._emit("engine/compile", "X", t0, t1,
+                 {"fun_name": str(kw.get("fun_name", "")), "phase": phase})
+    if scope is not None:
+        scope.add_compile(t0, t1, phase == "backend")
+
+
+class _Collection:
+    """The generation-2 collection in progress (one at a time)."""
+
+    t0 = 0.0
+    ann: Optional[TraceAnnotation] = None
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        if _route()[0] is not None:
+            _Collection.ann = TraceAnnotation("engine/gc")
+            _Collection.ann.__enter__()
+            _Collection.t0 = time.perf_counter()
+        return
+    if _Collection.ann is None:
+        return
+    t1 = time.perf_counter()
+    _Collection.ann.__exit__(None, None, None)
+    _Collection.ann = None
+    tracer, scope = _route()
+    if tracer is None:
+        return
+    tracer._emit("engine/gc", "X", _Collection.t0, t1,
+                 {"collected": info.get("collected", 0),
+                  "uncollectable": info.get("uncollectable", 0)})
+    if scope is not None:
+        scope.stage["gc"] += t1 - _Collection.t0
+
+
+def _attach(tracer: Tracer) -> "weakref.ref[Tracer]":
+    global _hooked
+    ref = weakref.ref(tracer)
+    with _HOOKS_LOCK:
+        _HUBS.append(ref)
+        if not _hooked:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            gc.callbacks.append(_on_gc)
+            _hooked = True
+    return ref
+
+
+def _release(ref: "weakref.ref[Tracer]") -> None:
+    """Drop a hub (closed or collected); the last one takes the hooks."""
+    global _hooked
+    with _HOOKS_LOCK:
+        _HUBS[:] = [r for r in _HUBS if r is not ref and r() is not None]
+        if not _HUBS and _hooked:
+            jax.monitoring.unregister_event_duration_listener(_on_duration)
+            if _on_gc in gc.callbacks:
+                gc.callbacks.remove(_on_gc)
+            _hooked = False
+
+
+def hooks_registered() -> bool:
+    """Whether the compile listener and the GC callback are installed."""
+    return _hooked
+
+
 class Obs:
     """Observability hub: tracer + flight recorder + exporters."""
 
@@ -213,6 +417,11 @@ class Obs:
         self.cfg = cfg if cfg is not None else ObsConfig()
         self.enabled = bool(self.cfg.enabled)
         self.tracer = Tracer(self.cfg) if self.enabled else NULL_TRACER
+        # the compile/GC hooks live while an enabled hub does: released
+        # by close() or when the tracer is collected, whichever is first
+        self._release = (weakref.finalize(self.tracer, _release,
+                                          _attach(self.tracer))
+                         if self.enabled else None)
         self.flight: Optional[FlightRecorder] = None
         if self.enabled and self.cfg.flight_n > 0:
             self.flight = FlightRecorder(self.cfg.flight_n,
@@ -223,12 +432,13 @@ class Obs:
         self.span = self.tracer.span
         self.context = self.tracer.context
         self.instant = self.tracer.instant
-        self._profiling = False
 
     # -- step lifecycle ---------------------------------------------------
 
-    def begin_step(self, step: int) -> None:
-        self.tracer.begin_step(step)
+    def begin_step(self, step: int) -> Optional[StepScope]:
+        """Open a traced step on the calling thread; returns its
+        :class:`StepScope` (None with tracing off)."""
+        return self.tracer.begin_step(step)
 
     def end_step(self, step: int) -> None:
         events = self.tracer.take_step()
@@ -253,35 +463,11 @@ class Obs:
         return self.flight.dump(reason=reason, path=path,
                                 triggered=triggered)
 
-    # -- jax.profiler session hook ---------------------------------------
-
-    @contextmanager
-    def profile_step(self, step: int):
-        """Bracket steps ``[profile_start, profile_stop)`` inside one
-        ``jax.profiler`` trace session (no-op unless configured)."""
-        cfg = self.cfg
-        active = (self.enabled and bool(cfg.profiler_dir)
-                  and cfg.profile_start <= step < cfg.profile_stop)
-        if active and not self._profiling:
-            import jax
-
-            jax.profiler.start_trace(cfg.profiler_dir)
-            self._profiling = True
-        try:
-            yield
-        finally:
-            if self._profiling and step >= cfg.profile_stop - 1:
-                import jax
-
-                jax.profiler.stop_trace()
-                self._profiling = False
-
     def close(self) -> None:
-        if self._profiling:
-            import jax
-
-            jax.profiler.stop_trace()
-            self._profiling = False
+        """Release this hub's share of the process-wide compile and GC
+        hooks (the last enabled hub to close removes them)."""
+        if self._release is not None:
+            self._release()
 
     # -- export -----------------------------------------------------------
 

@@ -425,6 +425,7 @@ class ServingRuntime:
         if self.rcfg.obs is not None:
             # runtime-level override: rebuild the shared hub so engine,
             # ingress, and executor spans land in ONE event stream
+            server.engine.obs.close()
             server.engine.obs = Obs(self.rcfg.obs)
         self.obs = server.obs
         self.stats: List[ServingStepStats] = []
@@ -727,7 +728,8 @@ class ServingRuntime:
         while not self._stop_now.is_set():
             if self.health is not None:
                 self.health.beat("executor", self.clock.now())
-            item = self._handoff.pop(timeout=0.05)
+            with obs.span("executor/wait"):
+                item = self._handoff.pop(timeout=0.05)
             if item is None:
                 if self._handoff.closed and len(self._handoff) == 0:
                     break
@@ -739,9 +741,10 @@ class ServingRuntime:
                 self._graph = g
                 t_done = self.clock.now()
                 self._last_service_s = t_done - t_exec0
-                _record_batch_latencies(self.telemetry, item, t_done)
-                if obs.enabled and item.arrivals:
-                    obs.observe_e2e(1e3 * (t_done - min(item.arrivals)))
+                with obs.span("executor/record"):
+                    _record_batch_latencies(self.telemetry, item, t_done)
+                    if obs.enabled and item.arrivals:
+                        obs.observe_e2e(1e3 * (t_done - min(item.arrivals)))
                 with obs.span("executor/fanout", n_deltas=len(st.deltas),
                               n_subs=len(self._subs)):
                     self.stats.append(st)

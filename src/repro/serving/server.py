@@ -67,6 +67,9 @@ class ServingStepStats:
     n_rejected: int = 0
     # per-stage wall seconds of this step (tracing on; empty otherwise)
     stage_s: Dict[str, float] = field(default_factory=dict)
+    # host-link bytes and compiles of this step (tracing on; empty
+    # otherwise) — StepOutput.counts
+    counts: Dict[str, int] = field(default_factory=dict)
 
     @property
     def n_new_patterns(self) -> int:
@@ -211,7 +214,8 @@ class MatchServer:
             subgraph_nodes=out.subgraph_nodes,
             subgraph_edges=out.subgraph_edges,
             n_dropped=dropped, n_evicted=evicted, n_rejected=rejected,
-            stage_s=dict(out.stage_s or {}))
+            stage_s=dict(out.stage_s or {}),
+            counts=dict(out.counts or {}))
         self.telemetry.record_step(st.total_s, n_events,
                                    st.n_new_patterns, out.frac_affected,
                                    n_dropped=dropped, n_evicted=evicted,

@@ -90,6 +90,20 @@ def build_ell(senders: np.ndarray, receivers: np.ndarray, n: int,
     given the row axis is padded (all-masked, row_ids=0) to that fixed
     capacity so same-bucket graphs share a jit signature.
     """
+    return ell_to_device(build_ell_host(senders, receivers, n, weights, k,
+                                        r_cap))
+
+
+def ell_to_device(ell: EllGraph) -> EllGraph:
+    """Upload an :class:`EllGraph` with numpy leaves to the device."""
+    return EllGraph(jnp.asarray(ell.cols), jnp.asarray(ell.vals),
+                    jnp.asarray(ell.row_ids), jnp.asarray(ell.mask), ell.n)
+
+
+def build_ell_host(senders: np.ndarray, receivers: np.ndarray, n: int,
+                   weights: Optional[np.ndarray] = None, k: int = 64,
+                   r_cap: Optional[int] = None) -> EllGraph:
+    """:func:`build_ell` with numpy leaves: nothing is on the device yet."""
     senders = np.asarray(senders, np.int64)
     receivers = np.asarray(receivers, np.int64)
     if weights is None:
@@ -117,8 +131,7 @@ def build_ell(senders: np.ndarray, receivers: np.ndarray, n: int,
     cols[rr, cc] = r
     vals[rr, cc] = w
     mask[rr, cc] = True
-    return EllGraph(jnp.asarray(cols), jnp.asarray(vals),
-                    jnp.asarray(row_ids), jnp.asarray(mask), n)
+    return EllGraph(cols, vals, row_ids, mask, n)
 
 
 def build_ell_sharded(senders: np.ndarray, receivers: np.ndarray, n: int,

@@ -2,9 +2,13 @@
 
 Pins the acceptance contract of the tracing/flight/export stack:
   * zero-cost disabled — with ``ObsConfig()`` (the default) the engine
-    emits NO spans, takes no extra device fences, compiles exactly as
-    many traces, and produces BITWISE the results of a build that never
-    heard of tracing;
+    emits NO spans, takes no extra device fences, registers no compile
+    listener or GC callback, compiles exactly as many traces, and
+    produces BITWISE the results of a build that never heard of tracing;
+  * host-path spans and counters — a traced induced step nests its
+    apply and extract children inside their parents, counts each device
+    array it pulls once, and names compiles and collections as
+    ``engine/compile`` / ``engine/gc`` spans, also in a profiler capture;
   * traced ≡ untraced — enabling tracing changes what is *recorded*,
     never what is computed: stores and deltas stay bitwise identical;
   * telemetry credibility — percentile keys are omitted (and strict
@@ -21,6 +25,7 @@ Pins the acceptance contract of the tracing/flight/export stack:
     events; Prometheus text skips non-numeric keys.
 """
 
+import gc
 import json
 import math
 import os
@@ -33,7 +38,8 @@ from repro.config.base import (IGPMConfig, ObsConfig, RuntimeConfig,
 from repro.core.query import (decompose, prefix_zoo, query_signature,
                               query_zoo)
 from repro.obs import (NULL_SPAN, NULL_TRACER, FlightRecorder, Obs,
-                       read_jsonl, validate_events, validate_exposition,
+                       StepScope, hooks_registered, read_jsonl, to_host,
+                       validate_events, validate_exposition,
                        validate_jsonl, write_chrome, write_jsonl,
                        write_prometheus)
 from repro.serving import MatchServer
@@ -148,15 +154,26 @@ def _outs_equal(a, b):
         assert sa.n_pruned == sb.n_pruned
 
 
+def _hooks():
+    from jax._src import monitoring
+    return (hooks_registered(),
+            list(monitoring.get_event_duration_listeners()),
+            list(gc.callbacks))
+
+
 @pytest.mark.slow
 def test_tracing_disabled_is_noop_and_enabled_is_bitwise_equal():
     stream = _stream()
+    hooks0 = _hooks()
     off = _server()
     outs_off = _run(off, stream)
-    # disabled = the null tracer: zero spans, shared no-op span object
+    # disabled = the null tracer: zero spans, shared no-op span object,
+    # no compile listener or GC callback, no stage or count records
     assert off.obs.tracer is NULL_TRACER
     assert off.obs.tracer.n_spans == 0
     assert off.obs.span("anything") is NULL_SPAN
+    assert _hooks() == hooks0
+    assert all(st.stage_s == {} and st.counts == {} for st in outs_off)
 
     on = _server(obs=ObsConfig(enabled=True))
     outs_on = _run(on, stream)
@@ -167,6 +184,7 @@ def test_tracing_disabled_is_noop_and_enabled_is_bitwise_equal():
         assert off.stores[i]._patterns == on.stores[i]._patterns
     # ...and never what is compiled: the extra fences sit outside jit
     assert off.engine.trace_count() == on.engine.trace_count()
+    assert all(st.counts["d2h_bytes"] > 0 for st in outs_on)
 
 
 @pytest.mark.slow
@@ -316,9 +334,11 @@ def test_lockstep_runtime_trace_spans_threads(tmp_path):
     by_cat = {}
     for ev in events:
         by_cat.setdefault(ev.get("cat"), []).append(ev)
-    # ingress spans and engine spans run on different threads
+    # ingress spans and engine spans run on different threads (compile
+    # and GC spans land on whichever thread compiled or collected)
     ingress_tids = {ev["tid"] for ev in by_cat["ingress"]}
-    engine_tids = {ev["tid"] for ev in by_cat["engine"]}
+    engine_tids = {ev["tid"] for ev in by_cat["engine"]
+                   if ev["name"] not in ("engine/compile", "engine/gc")}
     assert ingress_tids and engine_tids
     assert ingress_tids.isdisjoint(engine_tids)
     # thread_name metadata names both runtime threads
@@ -340,6 +360,185 @@ def test_lockstep_runtime_trace_spans_threads(tmp_path):
                for ev in merges)
     # the flight ring grouped those spans under their step ids
     assert srv.obs.flight.steps() == [s.step for s in stats]
+
+
+# -- host-path sub-spans, counters, compile and GC spans ----------------------
+
+APPLY_CHILDREN = ("engine/apply/update", "engine/apply/ell_refresh",
+                  "engine/apply/updated", "engine/apply/wait")
+EXTRACT_CHILDREN = ("engine/extract/pull", "engine/extract/select",
+                    "engine/extract/ell", "engine/extract/upload")
+
+
+def _induced_server(obs, **cfg_kw):
+    """The ELL backend with every step on the induced path (a recompute
+    set can never exceed twice the live vertices)."""
+    serving = ServingConfig(obs=obs, microbatch_window=64,
+                            full_graph_frac=2.0)
+    return MatchServer(_cfg(backend="ell", **cfg_kw), query_zoo(2), serving,
+                       seed=0)
+
+
+def _spans(server, name, step=None):
+    return [ev for ev in server.obs.tracer.events()
+            if ev["name"] == name and ev.get("ph") == "X"
+            and (step is None or ev["args"].get("step") == step)]
+
+
+@pytest.mark.slow
+def test_traced_induced_step_nests_sub_spans():
+    srv = _induced_server(ObsConfig(enabled=True))
+    outs = _run(srv, _stream(n_steps=2))
+    for st in outs:
+        assert st.subgraph_nodes < 128          # induced, not a storm
+        assert {"apply", "ell_refresh", "extract", "extract_pull",
+                "compile", "gc"} <= set(st.stage_s)
+        assert st.stage_s["extract_pull"] <= st.stage_s["extract"]
+        assert set(st.counts) == {"d2h_bytes", "h2d_bytes", "compiles"}
+        for parent, children in (("engine/apply", APPLY_CHILDREN),
+                                 ("engine/extract", EXTRACT_CHILDREN)):
+            (p,) = _spans(srv, parent, st.step)
+            covered = 0.0
+            for name in children:
+                (c,) = _spans(srv, name, st.step)
+                assert c["tid"] == p["tid"]
+                assert p["ts"] <= c["ts"]
+                assert c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-3
+                covered += c["dur"]
+            # the children cover the parent's body but for the glue
+            assert covered >= 0.5 * p["dur"]
+
+
+@pytest.mark.slow
+def test_d2h_bytes_count_each_pulled_array_once(monkeypatch):
+    import jax
+    srv = _induced_server(ObsConfig(enabled=True))
+    stream = _stream(n_steps=2)
+    g = stream.graph
+    srv.submit_update(stream.updates[0])
+    g, _ = srv.step(g)
+    pulled = []
+    orig = StepScope.pulled
+
+    def spy(self, x):
+        pulled.append(x)
+        orig(self, x)
+
+    monkeypatch.setattr(StepScope, "pulled", spy)
+    srv.submit_update(stream.updates[1])
+    g, st = srv.step(g)
+    device = [x for x in pulled if isinstance(x, jax.Array)]
+    unique = {id(x): x for x in device}
+    assert len(unique) < len(device)        # some arrays were read twice
+    assert st.counts["d2h_bytes"] == sum(x.nbytes for x in unique.values())
+    # the graph arrays of the step's own graph crossed exactly once each
+    for name in ("senders", "receivers", "edge_mask", "labels",
+                 "node_mask"):
+        arr = getattr(g, name)
+        assert sum(1 for x in unique.values() if x is arr) == 1
+    graph = sum(getattr(g, f).nbytes for f in
+                ("senders", "receivers", "edge_mask", "labels", "node_mask"))
+    assert graph <= st.counts["d2h_bytes"] < graph + 64 * 1024
+    assert st.counts["h2d_bytes"] > 0
+
+
+def test_to_host_counts_a_device_array_once_per_step():
+    import jax.numpy as jnp
+    obs = Obs(ObsConfig(enabled=True))
+    a = jnp.arange(100, dtype=jnp.int32)
+    b = jnp.ones((7, 3), jnp.float32)
+    first = obs.begin_step(0)
+    np.testing.assert_array_equal(to_host(a), np.arange(100))
+    to_host(a)                              # read again: counted once
+    to_host(b)
+    to_host(np.zeros(50))                   # host data crosses nothing
+    obs.end_step(0)
+    assert first.counts == {"d2h_bytes": a.nbytes + b.nbytes,
+                            "h2d_bytes": 0, "compiles": 0}
+    second = obs.begin_step(1)              # a new step starts afresh
+    to_host(a)
+    obs.end_step(1)
+    to_host(b)                              # outside a step: not counted
+    assert second.counts["d2h_bytes"] == a.nbytes
+    obs.close()
+
+
+def test_last_closed_hub_removes_the_hooks():
+    gc.collect()                            # drop hubs of earlier tests
+    base = hooks_registered()
+    a = Obs(ObsConfig(enabled=True))
+    b = Obs(ObsConfig(enabled=True))
+    assert hooks_registered()
+    a.close()
+    assert hooks_registered()
+    b.close()
+    assert hooks_registered() == base
+    assert not base
+
+
+@pytest.mark.slow
+def test_new_capacity_bucket_step_holds_compile_span():
+    # an rwr_iters no other test uses: the bucket programs are new here
+    srv = _induced_server(ObsConfig(enabled=True), rwr_iters=5)
+    outs = _run(srv, _stream(n_steps=3))
+    seen = set()
+    checked = 0
+    for st in outs:
+        bucket = (max(64, 1 << (st.subgraph_nodes - 1).bit_length()),
+                  max(256, 1 << (st.subgraph_edges - 1).bit_length()))
+        if bucket in seen:
+            continue
+        seen.add(bucket)
+        checked += 1
+        assert st.counts["compiles"] >= 1
+        assert st.stage_s["compile"] > 0.0
+        spans = _spans(srv, "engine/compile", st.step)
+        assert any(ev["args"]["phase"] == "backend" for ev in spans)
+        assert all(ev["args"]["fun_name"] is not None for ev in spans)
+    assert checked >= 1
+
+
+@pytest.mark.slow
+def test_gc_inside_a_traced_step_yields_gc_span():
+    srv = _server(obs=ObsConfig(enabled=True))
+    pem = srv.engine.pem
+    orig = pem.recompute_mask
+
+    def collecting(g, updated):
+        gc.collect()
+        return orig(g, updated)
+
+    pem.recompute_mask = collecting
+    outs = _run(srv, _stream(n_steps=1))
+    (st,) = outs
+    assert st.stage_s["gc"] > 0.0
+    (ev,) = [e for e in _spans(srv, "engine/gc", st.step)]
+    (p,) = _spans(srv, "engine/pem", st.step)
+    assert p["ts"] <= ev["ts"] and ev["ts"] + ev["dur"] <= p["ts"] + p["dur"]
+
+
+@pytest.mark.slow
+def test_profiler_capture_holds_program_spans(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    srv = _server(obs=ObsConfig(enabled=True))
+    stream = _stream(n_steps=2)
+    _run(srv, stream)                        # compile outside the capture
+    srv.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        _run(srv, stream)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for ev in line.events:
+                    names[ev.name] = names.get(ev.name, 0) + 1
+    assert names.get("engine/apply") == 2
+    assert names.get("engine/apply/update") == 2
+    assert "engine/merge" in names
 
 
 # -- exporters ----------------------------------------------------------------
