@@ -41,7 +41,7 @@ from repro.core.graph import (DynamicGraph, EdgePartition, EllCache,
 from repro.core.pem import PartialExecutionManager
 from repro.core.query import DagFull, Query, query_signature
 from repro.core.rwr import label_rwr, label_rwr_adaptive
-from repro.core.subgraph import (induced_tile, pull_graph, remap_matched,
+from repro.core.subgraph import (induced_tile, pull_induced, remap_matched,
                                   select_induced, upload_induced)
 from repro.engine.buckets import (QueryBucket, _pow2, bucket_shape,
                                   decode_strings, encode_strings)
@@ -935,9 +935,9 @@ def _engine_step(eng: Engine, state: EngineState, upd: UpdateBatch,
         else:
             with obs.span("engine/extract") as sp:
                 with obs.span("engine/extract/pull") as sp_pull:
-                    host = pull_graph(g)
+                    pulled = pull_induced(g, rec_mask)
                 with obs.span("engine/extract/select"):
-                    sel = select_induced(host, rec_mask)
+                    sel = select_induced(pulled)
                 tile = None
                 if eng.ell_cache is not None:
                     with obs.span("engine/extract/ell"):

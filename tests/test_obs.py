@@ -431,13 +431,15 @@ def test_d2h_bytes_count_each_pulled_array_once(monkeypatch):
     unique = {id(x): x for x in device}
     assert len(unique) < len(device)        # some arrays were read twice
     assert st.counts["d2h_bytes"] == sum(x.nbytes for x in unique.values())
-    # the graph arrays of the step's own graph crossed exactly once each
-    for name in ("senders", "receivers", "edge_mask", "labels",
-                 "node_mask"):
+    # the graph arrays the step reads on the host (prune's liveness mask,
+    # PEM, the live count) crossed exactly once each; the extraction
+    # selects on the device and reads none of them
+    read = ("senders", "receivers", "edge_mask", "node_mask")
+    for name in read:
         arr = getattr(g, name)
         assert sum(1 for x in unique.values() if x is arr) == 1
-    graph = sum(getattr(g, f).nbytes for f in
-                ("senders", "receivers", "edge_mask", "labels", "node_mask"))
+    assert not any(x is g.labels for x in unique.values())
+    graph = sum(getattr(g, f).nbytes for f in read)
     assert graph <= st.counts["d2h_bytes"] < graph + 64 * 1024
     assert st.counts["h2d_bytes"] > 0
 

@@ -1,12 +1,14 @@
-"""Compile-only checks of the ELL kernels for a TPU v5e that is described,
-not attached.
+"""Compile-only checks of the ELL kernels and of the induced selection's
+two programs for a TPU v5e that is described, not attached.
 
 Mosaic is stricter than interpret mode (gather lowering, block tiling,
 scoped VMEM), so these compile both kernels with the installed TPU
 compiler at the paper width (``n_max`` 262,144, ``ell_width`` 64, RHS
 widths 4 and 32 — the label-RWR table and the widest block ``ops.py``
 issues at that ``n``) and at the reduced config's width, each reducing the
-mirror's rows to its ``n`` vertices in the kernel. Nothing runs.
+mirror's rows to its ``n`` vertices in the kernel; the selection's loop
+over the paper-size graph and its compaction at a steady-size and a
+catch-up-size bucket compile too. Nothing runs.
 The topology is described inside a fixture, never at import, so every
 xdist worker collects the same tests and only the one running this file
 loads the TPU compiler.
@@ -76,3 +78,29 @@ def test_ell_kernel_compiles_for_v5e(one_chip, kernel, size, d):
     assert "scatter" not in text       # rows reduce to vertices in-kernel
     out = compiled.out_info
     assert out.shape == (n, d) and out.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("phase,n_cap,e_cap", [
+    ("select", None, None), ("compact", 2_048, 8_192),
+    ("compact", 32_768, 524_288)])
+def test_induced_selection_compiles_for_v5e(one_chip, phase, n_cap, e_cap):
+    from repro.core.subgraph import _compact, _select
+    n, e_max, _ = PAPER
+    if phase == "select":
+        args = (_spec((e_max,), jnp.int32, one_chip),
+                _spec((e_max,), jnp.int32, one_chip),
+                _spec((e_max,), jnp.bool_, one_chip),
+                _spec((), jnp.int32, one_chip),
+                _spec((n,), jnp.bool_, one_chip))
+        out = _select.lower(*args).compile().out_info
+        assert [o.shape for o in out] == [(e_max + 65_536,)] * 2 + [()]
+    else:
+        pad = e_max + 65_536
+        args = (_spec((pad,), jnp.int32, one_chip),
+                _spec((pad,), jnp.int32, one_chip),
+                _spec((), jnp.int32, one_chip),
+                _spec((n,), jnp.int32, one_chip),
+                _spec((n_cap,), jnp.int32, one_chip))
+        out = _compact.lower(*args, e_cap=e_cap).compile().out_info
+        assert out.senders.shape == (e_cap,)
+        assert out.labels.shape == (n_cap,)
