@@ -17,8 +17,9 @@ import numpy as np
 METRICS_DIR = Path(__file__).resolve().parent / "metrics"
 
 
-def load_reader(name: str) -> Callable:
-    path = METRICS_DIR / f"{name}.py"
+def load_reader(name: str, directory: Path = METRICS_DIR) -> Callable:
+    """The ``read`` function of ``<directory>/<name>.py``."""
+    path = directory / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"metric {name!r} has no reader at {path}")
     spec = importlib.util.spec_from_file_location(

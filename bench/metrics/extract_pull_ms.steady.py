@@ -1,5 +1,6 @@
-"""Mean per step of the engine/extract/pull span: the device→host copies of
-the graph arrays induced extraction reads (ms).
+"""Mean per step of the engine/extract/pull span: the recompute mask's
+upload, the device's selection and compaction of the induced arcs, the sync
+on their count, and the copy of the kept arcs and labels to the host (ms).
 """
 
 from bench.measures import stage_ms

@@ -11,11 +11,12 @@ from bench import generator as gen
 ROOT = Path(__file__).resolve().parents[2]
 
 
-def _cells():
-    b = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cfgs = {c["name"]: json.loads((ROOT / c["file"]).read_text())
+def _cells(root: Path = ROOT):
+    b = json.loads((root / "BENCHMARK.json").read_text())
+    cfgs = {c["name"]: json.loads((root / c["file"]).read_text())
             for c in b["configs"]}
-    return [(w["name"], cfgs[w["config"]], gen.traffic_from_file(w["traffic"]),
+    return [(w["name"], cfgs[w["config"]],
+             gen.traffic_from_file(w["traffic"], root / "bench" / "traffic"),
              b["run_seconds"]) for w in b["workloads"]]
 
 
@@ -84,9 +85,10 @@ def test_tail_is_sized_from_edges_per_step_and_raises_when_short():
         gen.make_stream(twin, 0.99, 30000, 3)
 
 
-@pytest.mark.parametrize("cell", [c[0] for c in _cells()])
-def test_each_cell_stream_covers_warmup_and_window(cell):
-    name, cfg, tr, seconds = next(c for c in _cells() if c[0] == cell)
+def check_stream(root: Path, cell: str) -> None:
+    """The cell's stream holds its start graph and every event its
+    warm-up, window and tail offer."""
+    name, cfg, tr, seconds = next(c for c in _cells(root) if c[0] == cell)
     twin = gen.twin_from_config(cfg)
     stream, warm, win, tail = gen.build_inputs(twin, tr, seconds, 99)
     assert len(stream.tail_src) == (warm.n_events + win.n_events
@@ -99,3 +101,8 @@ def test_each_cell_stream_covers_warmup_and_window(cell):
     # the start graph holds the configured share of the stream
     assert len(stream.start_src) == pytest.approx(
         tr.warmup_frac * twin.n_edges, rel=0.02)  # self-loops dropped
+
+
+@pytest.mark.parametrize("cell", [c[0] for c in _cells()])
+def test_each_cell_stream_covers_warmup_and_window(cell):
+    check_stream(ROOT, cell)

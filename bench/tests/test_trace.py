@@ -57,6 +57,31 @@ def test_reduction_by_hand():
     assert ops["copy f32[8] copy"] == pytest.approx(0.5e-3)
 
 
+
+def test_idle_gaps_go_to_the_executor_thread_first():
+    ms = 1e6  # ns
+    copy = "%copy.2 = f32[8] copy(f32[8] %c)"
+    # the device idles over [1, 5], [5.5, 9] and [9.5, 12] ms; a gap goes
+    # to the span at its midpoint: 3, 7.25 and 10.75 ms
+    ex = {"window": [0.0, 12 * ms],
+          "devices": {"/device:TPU:0": [[copy, 0.0, 1 * ms],
+                                        [copy, 5 * ms, 0.5 * ms],
+                                        [copy, 9 * ms, 0.5 * ms]]},
+          # thread 2 runs the executor and the engine's step; thread 1 is
+          # the ingress, whose shorter spans overlap the step's
+          "spans": [["executor/step", 0.0, 10 * ms, 2],
+                    ["engine/apply", 1 * ms, 4 * ms, 2],
+                    ["ingress/assemble", 2.5 * ms, 1 * ms, 1],
+                    ["ingress/offer", 6.5 * ms, 1.5 * ms, 1],
+                    ["ingress/offer", 10.5 * ms, 1 * ms, 1]]}
+    gaps = dict(trace.reduce(ex)["idle_gaps"])
+    assert gaps["engine/apply"] == pytest.approx(4e-3)
+    assert gaps["executor/step"] == pytest.approx(3.5e-3)
+    # past the step's end the executor's thread has no span: the ingress's
+    assert gaps["ingress/offer"] == pytest.approx(2.5e-3)
+    assert "ingress/assemble" not in gaps
+
+
 def test_short_names():
     assert trace.short_name(SUMS) == "ell_vertex_sums f32[512,128] custom-call"
     assert trace.short_name(WHILE) == "while tuple while"

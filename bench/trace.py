@@ -105,7 +105,7 @@ def extract(trace_dir: str, anchor: dict, obs, t0: float,
             pc = epoch + ev["ts"] / 1e6
             spans.append([ev["name"],
                           anchor_ns + (pc - anchor["perf_counter"]) * 1e9,
-                          ev["dur"] * 1e3])
+                          ev["dur"] * 1e3, ev["tid"]])
     return {"window": [mono_ns(t0), mono_ns(t_end)], "devices": devices,
             "spans": spans, "clock": [anchor_ns, anchor["monotonic"]]}
 
@@ -156,19 +156,16 @@ def out_rows(op: str) -> Optional[int]:
     return dims[-2] if len(dims) >= 2 else None
 
 
-class _Stages:
-    """Host stage spans (engine/executor/ingress), queried by time."""
+class _Line:
+    """Spans of some threads, queried for the innermost one at a time."""
 
-    def __init__(self, spans: List[list]):
-        keep = sorted((s, s + d, n) for n, s, d in spans
-                      if n.split("/", 1)[0] in ("engine", "executor",
-                                                "ingress"))
-        self._starts = [s for s, _, _ in keep]
-        self._spans = keep
-        self._max = max((e - s for s, e, _ in keep), default=0.0)
+    def __init__(self, spans: List[Tuple[float, float, str]]):
+        self._spans = sorted(spans)
+        self._starts = [s for s, _, _ in self._spans]
+        self._max = max((e - s for s, e, _ in self._spans), default=0.0)
 
-    def at(self, t: float) -> str:
-        """The innermost span covering ``t``."""
+    def at(self, t: float) -> Optional[str]:
+        """The innermost span covering ``t``, or None."""
         i = bisect.bisect_right(self._starts, t)
         best = None
         while i > 0:
@@ -178,7 +175,38 @@ class _Stages:
                 break
             if s <= t <= e and (best is None or e - s < best[1]):
                 best = (n, e - s)
-        return best[0] if best else "no span"
+        return best[0] if best else None
+
+
+class _Stages:
+    """Host stage spans (engine/executor/ingress), queried by time.
+
+    A span is ``[name, start, dur, tid]`` (a span without a thread id lies
+    on one unnamed thread). The executor's threads, those that run
+    ``executor/*`` spans and the engine's step inside them, come first:
+    a time goes to the innermost of their spans, and to another thread's
+    only where they have none, so that a short ``ingress/*`` span does not
+    take an idle gap from the step that was holding the device."""
+
+    def __init__(self, spans: List[list]):
+        keep = [(n, s, s + d, rest[0] if rest else None)
+                for n, s, d, *rest in spans
+                if n.split("/", 1)[0] in ("engine", "executor", "ingress")]
+        executor = {tid for n, _, _, tid in keep
+                    if n.startswith("executor/")}
+        self._lines = [
+            _Line([(s, e, n) for n, s, e, tid in keep if tid in executor]),
+            _Line([(s, e, n) for n, s, e, tid in keep
+                   if tid not in executor])]
+
+    def at(self, t: float) -> str:
+        """The innermost span covering ``t`` on the executor's threads,
+        else on the others."""
+        for line in self._lines:
+            name = line.at(t)
+            if name is not None:
+                return name
+        return "no span"
 
 
 def _leaves(ops: List[list]) -> List[bool]:
